@@ -9,13 +9,24 @@ container is one CPU, so the benchmarks run the same *algorithm* at
 """
 from __future__ import annotations
 
+import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.tune import COST_MODEL_VERSION
+
+
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compile cache where ``JAX_COMPILATION_CACHE_DIR``
+    says, else in the fixed ``.jax_cache/`` at the repo root (the path is
+    part of the cache key, so it must not move between runs)."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        root = Path(__file__).resolve().parent.parent
+        jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
 
 
 def timeit(fn, *args, warmup=2, iters=5):

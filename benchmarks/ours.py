@@ -61,32 +61,33 @@ def sort_collective_schedule():
     CONSTANT number of collectives (all-gather samples + fused bucket
     all_to_all + counts all_to_all + overflow psum), independent of p —
     the paper's design needs O(p) point-to-point messages per processor.
-    Verified by parsing the compiled HLO of distributed_sort."""
+    Verified by parsing the compiled HLO of distributed_sort, compiled in
+    this process for the devices it has, or — with a single device — for
+    a described four-chip TPU v5e host (compiled, not run)."""
     import re
-    import subprocess
-    import sys
-    import os
+    from collections import Counter
 
-    code = """
-import numpy as np, jax, jax.numpy as jnp, re
-from repro.core import SortConfig, distributed_sort
-mesh = jax.make_mesh((8,), ("data",))
-x = jax.ShapeDtypeStruct((8 * 4096,), jnp.float32)
-import functools
-f = jax.jit(functools.partial(distributed_sort, mesh=mesh, axis_name="data",
-                              config=SortConfig(use_pallas=False)))
-hlo = f.lower(jnp.zeros(8*4096, jnp.float32)).compile().as_text()
-ops = re.findall(r"= \\S+ (all-gather|all-reduce|all-to-all|reduce-scatter|collective-permute)\\(", hlo)
-from collections import Counter
-print(dict(Counter(ops)))
-"""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env=env, timeout=600)
-    counts = r.stdout.strip().splitlines()[-1] if r.returncode == 0 else f"err:{r.stderr[-120:]}"
-    emit("sort_collective_schedule", 0.0, f"ops_per_sort={counts};paper=O(p)_messages")
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core import sample_sort
+
+    devices = jax.devices()
+    if len(devices) < 2:
+        from jax.experimental import topologies
+
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    p = len(devices)
+    mesh = jax.make_mesh((p,), ("data",), devices=devices)
+    f = sample_sort._mesh_program(mesh, "data", SortConfig(use_pallas=False),
+                                  True, False)
+    x = jax.ShapeDtypeStruct((p, 4096), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data")))
+    hlo = f.lower(x).compile().as_text()
+    ops = re.findall(r"\s(all-gather|all-reduce|all-to-all|reduce-scatter|"
+                     r"collective-permute)(?:-start)?\(", hlo)
+    emit("sort_collective_schedule", 0.0,
+         f"p={p};ops_per_sort={dict(Counter(ops))};paper=O(p)_messages")
 
 
 def kernel_paths():

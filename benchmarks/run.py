@@ -73,6 +73,8 @@ def main() -> None:
     from benchmarks import (api_bench, common, external_sort, ours,
                             paper_figs, serve_bench)
 
+    common.use_compile_cache()
+
     suites = {
         "paper": {
             "fig5": paper_figs.fig5_distributions,

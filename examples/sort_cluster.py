@@ -1,17 +1,11 @@
 """Distributed sort on a real device mesh through the unified front end
-(`repro.sort(x, where=mesh)` -> shard_map + jax.lax collectives). Spawns
-8 virtual host devices if launched on one.
+(`repro.sort(x, where=mesh)` -> shard_map + jax.lax collectives), over
+every device JAX sees: the chips of a TPU host, or virtual CPU devices.
 
-    PYTHONPATH=src python examples/sort_cluster.py
+    python examples/sort_cluster.py                 # the chips this host has
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
+        PYTHONPATH=src python examples/sort_cluster.py   # 8 virtual devices
 """
-import os
-import sys
-
-if "XLA_FLAGS" not in os.environ and __name__ == "__main__":
-    # re-exec with 8 virtual devices (before jax initializes)
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    os.execv(sys.executable, [sys.executable] + sys.argv)
-
 import jax
 import numpy as np
 
@@ -19,21 +13,25 @@ import repro
 
 
 def main():
-    print(f"devices: {len(jax.devices())}")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    devices = jax.devices()
+    p = len(devices)
+    # a (data, model) mesh when the device count splits in two, else 1-D
+    model = 2 if p % 2 == 0 and p > 2 else 1
+    mesh = jax.make_mesh((p // model, model), ("data", "model"))
+    print(f"devices: {p} ({devices[0].device_kind}); mesh {dict(mesh.shape)}")
     rng = np.random.default_rng(0)
     cfg = repro.SortConfig(capacity_factor=1.5)
 
-    # sort 1M keys over the "data" axis (4 processors) — where=mesh pins
+    # sort 1M keys over the "data" axis — where=mesh pins
     # the mesh backend; everything else (plan, result type) is unchanged
     x = rng.normal(0, 1, 1 << 20).astype(np.float32)
     print(repro.explain(x, where=(mesh, "data"), config=cfg))
     r = repro.sort(x, where=(mesh, "data"), config=cfg)
     assert r.meta.backend == "mesh"
     assert (np.diff(r.keys) >= 0).all()
-    print(f"4-proc distributed sort ok; per-proc counts {r.counts}")
+    print(f"{mesh.shape['data']}-proc distributed sort ok; per-proc counts {r.counts}")
 
-    # multi-axis sort over ("data","model") = 8 processors — the multi-pod
+    # multi-axis sort over ("data","model") = every device — the multi-pod
     # pattern (axis tuples work in every collective); descending + argsort
     # work here exactly as on every other backend
     keys = rng.integers(1, 6, 1 << 20).astype(np.int32)  # heavy duplication
@@ -41,7 +39,7 @@ def main():
                      where=(mesh, ("data", "model")), config=cfg)
     counts = np.asarray(rkv.counts)
     assert np.array_equal(keys[rkv.values], rkv.keys)
-    print(f"8-proc kv sort under duplication: counts {counts} "
+    print(f"{p}-proc kv sort under duplication: counts {counts} "
           f"(max/mean {counts.max()/counts.mean():.4f})")
 
     rd = repro.sort(keys, order="desc", where=(mesh, ("data", "model")), config=cfg)

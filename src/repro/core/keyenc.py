@@ -662,6 +662,15 @@ def compact_rows(grid: jnp.ndarray, counts, m: int) -> jnp.ndarray:
     return buf[:m]
 
 
+def _replicated(x):
+    """A mesh backend's rows arrive sharded over the sort axis (explicit
+    mesh axes); compaction walks every row in order, so the decode runs on
+    a full copy of the grid. Needs the caller's ``jax.set_mesh``."""
+    if x is None or all(s is None for s in jax.typeof(x).sharding.spec):
+        return x
+    return jax.sharding.reshard(x, jax.sharding.PartitionSpec())
+
+
 @functools.partial(
     jax.jit, static_argnames=("m", "descending", "want_order", "packspec")
 )
@@ -705,6 +714,8 @@ def decode_grid(keys_grid, counts, values_grid=None, *, m: int,
     from repro.core.local_sort import segment_stable_kv
     from repro.kernels.ops import sentinel_for
 
+    keys_grid, counts, values_grid = (
+        _replicated(x) for x in (keys_grid, counts, values_grid))
     ks = compact_rows(keys_grid, counts, m)
     vs = None
     if values_grid is not None:

@@ -31,10 +31,7 @@ def merge_padded_runs(runs: jnp.ndarray, *, use_pallas: bool = True) -> jnp.ndar
     Sentinel padding (+inf / INT_MAX) must already sit at each row's tail.
     """
     fill = kops.sentinel_for(runs.dtype)
-    runs = _pad_runs_pow2(runs, fill)
-    while runs.shape[0] > 1:
-        runs = kops.merge_rows(runs[0::2], runs[1::2], use_pallas=use_pallas)
-    return runs[0]
+    return kops.merge_tree(_pad_runs_pow2(runs, fill), use_pallas=use_pallas)
 
 
 def merge_padded_runs_kv(
@@ -47,11 +44,6 @@ def merge_padded_runs_kv(
     """Key/value variant; the value payload rides the same permutation."""
     kfill = kops.sentinel_for(keys.dtype)
     vfill = kops.sentinel_for(values.dtype)
-    keys = _pad_runs_pow2(keys, kfill)
-    values = _pad_runs_pow2(values, vfill)
-    while keys.shape[0] > 1:
-        keys, values = kops.merge_rows_kv(
-            keys[0::2], values[0::2], keys[1::2], values[1::2],
-            stable=stable, use_pallas=use_pallas,
-        )
-    return keys[0], values[0]
+    return kops.merge_tree_kv(_pad_runs_pow2(keys, kfill),
+                              _pad_runs_pow2(values, vfill),
+                              stable=stable, use_pallas=use_pallas)

@@ -43,6 +43,7 @@ from repro.core.overflow import (
 )
 from repro.core.result import SortMeta, SortOutput
 from repro.core.splitters import SortConfig
+from repro.kernels import ops as kops
 from repro.obs import metrics as obs_metrics
 from repro.obs import tracing as obs_tracing
 from repro.obs.tracing import maybe_span as _span
@@ -474,6 +475,12 @@ def _make_plan(req: _Req, where, limits: SortLimits | None,
             f"x64 mode: {key_width}-bit key lane admitted "
             f"(sentinels/staging widen per dtype)"
         )
+    if req.config.use_pallas and not req.is_iterator:
+        dts = ([packspec.pack_dtype] if packspec is not None
+               else [k.dtype for k in req.keys] if req.multikey else [req.dtype])
+        if req.values is not None:
+            dts.append(req.values.dtype)
+        reasons.append(f"row sorts and merges: {kops.kernel_path(*dts)}")
     return SortPlan(
         backend=choice, n_procs=n_procs, chunk_elems=chunk_elems,
         limits=limits, reasons=tuple(reasons), mesh=mesh, axis_name=axis_name,
@@ -719,7 +726,6 @@ def _stitch_bucket_ties(ks: np.ndarray, vs: np.ndarray, bucket_sizes,
 
 
 def _sentinel(dtype) -> np.ndarray:
-    from repro.kernels import ops as kops
     import jax.numpy as jnp
 
     return np.asarray(kops.sentinel_for(jnp.dtype(dtype)))
@@ -920,7 +926,8 @@ def _exec_sim(req: _Req, plan: SortPlan) -> SortOutput:
 
 
 def _exec_mesh(req: _Req, plan: SortPlan) -> SortOutput:
-    import jax.numpy as jnp
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     tr = req.trace
     with _span(tr, "encode"):
@@ -931,21 +938,28 @@ def _exec_mesh(req: _Req, plan: SortPlan) -> SortOutput:
         p *= plan.mesh.shape[a]
     per = max(1, -(-req.n // p))
     m = req.n
+    # host inputs are copied straight into each device's shard
+    shards = NamedSharding(plan.mesh, P(axes))
+
+    def stage(x):
+        if isinstance(x, jax.Array):
+            # a device array (possibly already mesh-sharded) goes straight
+            # to shard_map — no host materialization round-trip
+            return x.reshape(-1)
+        return jax.device_put(np.asarray(x).reshape(-1), shards)
+
     with _span(tr, "stage") as sp:
         pad = p * per - m
         if pad == 0:
-            # divisible: pass the (possibly mesh-sharded) array straight to
-            # shard_map — no host materialization round-trip
-            xk = jnp.asarray(enc).reshape(-1)
-            xv = (jnp.asarray(payload).reshape(-1)
-                  if payload is not None else None)
+            xk = stage(enc)
+            xv = stage(payload) if payload is not None else None
         else:
             flat = np.asarray(enc).reshape(-1)
-            xk = jnp.asarray(_pad_grid(flat, p, per, _sentinel(flat.dtype)).reshape(-1))
+            xk = stage(_pad_grid(flat, p, per, _sentinel(flat.dtype)))
             xv = None
             if payload is not None:
                 vflat = np.asarray(payload).reshape(-1)
-                xv = jnp.asarray(_pad_grid(vflat, p, per, _sentinel(vflat.dtype)).reshape(-1))
+                xv = stage(_pad_grid(vflat, p, per, _sentinel(vflat.dtype)))
         if tr is not None:
             sp.fence((xk, xv))
 
@@ -971,7 +985,7 @@ def _exec_mesh(req: _Req, plan: SortPlan) -> SortOutput:
         def run(cfg):
             with tr.span("sort", phases="local_sort+splitter+exchange+merge") as sp:
                 res = sp.fence(fused(cfg))
-                sp.counts(list(res.count))
+                sp.counts(np.asarray(res.count).tolist())
             return res
 
     res, cfg_used, retries = run_with_capacity_retry(
@@ -980,8 +994,11 @@ def _exec_mesh(req: _Req, plan: SortPlan) -> SortOutput:
     )
 
     kg, vg = (res.values, None) if xv is None else (res.keys, res.values)
-    materialize = _grid_materialize(req, plan, kg, vg, res.count, m,
-                                    descending, reverse)
+    # the rows are sharded over the sort axis; the device decode gathers
+    # them under this mesh (keyenc.decode_grid)
+    with jax.set_mesh(plan.mesh):
+        materialize = _grid_materialize(req, plan, kg, vg, res.count, m,
+                                        descending, reverse)
     meta = _meta(req, plan, "mesh", cfg_used, retries)
     return SortOutput(
         meta,

@@ -22,6 +22,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import merge as merge_lib
@@ -29,11 +30,14 @@ from repro.core import splitters as spl
 from repro.core.local_sort import local_sort, local_sort_kv
 from repro.core.sim import _gather_buckets, _gather_buckets_kv
 from repro.kernels import ops as kops
-from repro.sharding.spec import axis_size_compat, shard_map_compat
 
 
 class ShardSortResult(NamedTuple):
-    """Per-device (local view inside shard_map) sort result."""
+    """Sort result. Inside shard_map each field is one device's local
+    view; ``distributed_sort`` returns the global view, a leading axis of
+    one row per device sharded over the sort axis. On a ``jax.make_mesh``
+    mesh (explicit axes) a row is read on the host, e.g.
+    ``np.asarray(r.values)[i, :counts[i]]``."""
 
     values: jnp.ndarray  # (total_capacity,) sorted, sentinel padded
     count: jnp.ndarray  # () valid prefix length
@@ -49,9 +53,6 @@ class ShardSortKVResult(NamedTuple):
     send_counts: jnp.ndarray
 
 
-_axis_size = axis_size_compat
-
-
 def sample_sort_shard(
     x_local: jnp.ndarray,
     axis_name,
@@ -60,7 +61,7 @@ def sample_sort_shard(
     investigator: bool = True,
 ) -> ShardSortResult:
     """Body to be called *inside* shard_map/pmap over ``axis_name``."""
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     (n,) = x_local.shape
     cap = config.capacity(p, n)
 
@@ -105,7 +106,7 @@ def sample_sort_shard_kv(
     investigator: bool = True,
 ) -> ShardSortKVResult:
     """Key/value body (provenance / MoE dispatch) inside shard_map."""
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     (n,) = keys_local.shape
     cap = config.capacity(p, n)
 
@@ -170,12 +171,13 @@ def _mesh_program(mesh, axis_name, config, investigator: bool, kv: bool):
                 r.send_counts[None],
             )
 
-        f = shard_map_compat(
+        f = jax.shard_map(
             wrapped,
             mesh=mesh,
             in_specs=(P(axes), P(axes)),
             out_specs=ShardSortKVResult(P(axes), P(axes), P(axes), P(axes),
                                         P(axes)),
+            check_vma=False,
         )
     else:
         body = functools.partial(
@@ -190,11 +192,12 @@ def _mesh_program(mesh, axis_name, config, investigator: bool, kv: bool):
                 r.send_counts[None],
             )
 
-        f = shard_map_compat(
+        f = jax.shard_map(
             wrapped,
             mesh=mesh,
             in_specs=P(axes),
             out_specs=ShardSortResult(P(axes), P(axes), P(axes), P(axes)),
+            check_vma=False,
         )
     return jax.jit(f)
 
@@ -218,7 +221,7 @@ def _mesh_phase_programs(mesh, axis_name, config, investigator: bool):
 
     def split_body(xsl):
         xs = xsl[0]
-        p = _axis_size(axis_name)
+        p = jax.lax.axis_size(axis_name)
         (n,) = xs.shape
         cap = config.capacity(p, n)
         s = config.num_samples(p, n, key_bytes=xs.dtype.itemsize)
@@ -236,7 +239,7 @@ def _mesh_phase_programs(mesh, axis_name, config, investigator: bool):
 
     def exch_body(xsl, bl):
         xs, bounds = xsl[0], bl[0]
-        p = _axis_size(axis_name)
+        p = jax.lax.axis_size(axis_name)
         (n,) = xs.shape
         cap = config.capacity(p, n)
         fill = kops.sentinel_for(xs.dtype)
@@ -255,16 +258,14 @@ def _mesh_phase_programs(mesh, axis_name, config, investigator: bool):
         merged = merge_lib.merge_padded_runs(rl[0], use_pallas=config.use_pallas)
         return merged[None]
 
-    local_f = jax.jit(shard_map_compat(
-        local_body, mesh=mesh, in_specs=P(axes), out_specs=P(axes)))
-    split_f = jax.jit(shard_map_compat(
-        split_body, mesh=mesh, in_specs=P(axes),
-        out_specs=(P(axes), P(axes), P(axes))))
-    exch_f = jax.jit(shard_map_compat(
-        exch_body, mesh=mesh, in_specs=(P(axes), P(axes)),
-        out_specs=(P(axes), P(axes))))
-    merge_f = jax.jit(shard_map_compat(
-        merge_body, mesh=mesh, in_specs=P(axes), out_specs=P(axes)))
+    def program(body, in_specs, out_specs):
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
+
+    local_f = program(local_body, P(axes), P(axes))
+    split_f = program(split_body, P(axes), (P(axes), P(axes), P(axes)))
+    exch_f = program(exch_body, (P(axes), P(axes)), (P(axes), P(axes)))
+    merge_f = program(merge_body, P(axes), P(axes))
     return local_f, split_f, exch_f, merge_f
 
 
@@ -294,10 +295,10 @@ def distributed_sort_phased(
         sp.set(overflowed=bool(jnp.any(overflowed)))
     with trace.span("exchange") as sp:
         recv, counts = sp.fence(exch_f(xs, bounds))
-        sp.counts(list(counts))
+        sp.counts(np.asarray(counts).tolist())
     with trace.span("merge") as sp:
         merged = sp.fence(merge_f(recv))
-        sp.counts(list(counts))
+        sp.counts(np.asarray(counts).tolist())
     return ShardSortResult(merged, counts, overflowed, send_counts)
 
 

@@ -15,6 +15,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,9 +63,11 @@ class SortConfig:
 def regular_sample(xs_sorted: jnp.ndarray, s: int) -> jnp.ndarray:
     """Regularly-spaced samples from a locally sorted shard (paper step 2)."""
     n = xs_sorted.shape[0]
-    # centered strides — same estimator as PSRS regular sampling
-    idx = ((2 * jnp.arange(s, dtype=jnp.int32) + 1) * n) // (2 * s)
-    return xs_sorted[idx]
+    # centered strides — same estimator as PSRS regular sampling. Static
+    # int64 host math: (2s-1)*n passes 2^31 once n*s does (2^23 keys per
+    # shard at the default 2048 samples)
+    idx = ((2 * np.arange(s, dtype=np.int64) + 1) * n) // (2 * s)
+    return xs_sorted[idx.astype(np.int32)]
 
 
 def select_splitters(all_samples: jnp.ndarray, p: int) -> jnp.ndarray:

@@ -26,7 +26,7 @@ config pattern:
     is on.
 
 ``x64_mode()`` is the scoped variant for tests and benchmarks: it sets
-the library flag and enters ``jax.experimental.enable_x64`` so the
+the library flag and enters ``jax.enable_x64(True)`` so the
 *thread-local* jax trace context widens, then restores both on exit —
 nothing leaks into subsequent 32-bit work on the same thread. (The jax
 x64 flag is part of the jit trace key, so toggling retraces programs
@@ -99,17 +99,17 @@ def enable_x64(on: bool = True) -> None:
 def x64_mode(on: bool = True):
     """Scoped x64 mode for tests/benchmarks: restores everything on exit.
 
-    Sets the library flag and enters ``jax.experimental.enable_x64``
+    Sets the library flag and enters ``jax.enable_x64(True)``
     (thread-local jax trace context), so code after the block — on this
     thread — is back on the 32-bit contract with no global state left
     behind."""
-    from jax.experimental import enable_x64 as _jax_enable_x64
+    import jax
 
     prev = _STATE["enabled"]
     _STATE["enabled"] = bool(on)
     try:
         if on:
-            with _jax_enable_x64():
+            with jax.enable_x64(True):
                 yield
         else:
             yield

@@ -1,15 +1,17 @@
 """Pallas TPU bitonic sorting-network kernels.
 
-Hardware adaptation (DESIGN.md §2): the paper sorts each worker thread's
-slice with quicksort — a branchy, data-dependent algorithm that maps poorly
-to the TPU vector unit. We replace it with a *bitonic sorting network*: an
+Hardware adaptation: the paper sorts each worker thread's slice with
+quicksort — a branchy, data-dependent algorithm that maps poorly to the
+TPU vector unit. We replace it with a *bitonic sorting network*: an
 oblivious, fixed compare-exchange schedule that vectorizes perfectly and
 runs entirely out of VMEM tiles.
 
-Every compare-exchange stage is expressed as a static reshape
-``(rows, n_blocks, 2, j)`` + ``where`` swap, so the whole network lowers to
-pure VPU ops — no gathers, no scatters. For a row of length N = 2**k the
-network has k*(k+1)/2 stages (k=11 → 66 for N=2048), each O(N) work.
+Every compare-exchange stage at distance ``j`` fetches each lane's
+partner (lane ``i ^ j``) with two lane rotations (``pltpu.roll`` by ``j``
+and by ``n - j``) and a select, so the row keeps its (sublane, lane)
+layout: no reshape of the lane dimension, no gathers, no scatters. For a
+row of length N = 2**k the network has k*(k+1)/2 stages (k=10 → 55 for
+N=1024), each O(N) work.
 
 Kernels:
   * ``_sort_kernel``      — sort each row of a (R, N) block, keys only.
@@ -20,10 +22,12 @@ Kernels:
   * ``_merge_kv_kernel``  — merge two sorted rows via the bitonic *merge*
                             half-network (k+1 stages, not O(k^2)): this is
                             the paper's Fig. 2 balanced pairwise merge,
-                            TPU-style (reverse + concat = bitonic sequence).
+                            TPU-style (a ++ reverse(b) is bitonic; the
+                            reversal happens in the XLA wrapper, because
+                            Mosaic has no lane-reversal primitive).
 
-All padding / pow2 handling lives in ``ops.py``; kernels assume N is a
-power of two.
+All padding / pow2 / dtype handling lives in ``ops.py``; kernels assume N
+is a power of two of at least one 128-lane vector and 32-bit elements.
 """
 from __future__ import annotations
 
@@ -33,18 +37,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-
-def _dir_mask(n_blocks: int, j: int, stage_span: int) -> jnp.ndarray:
-    """Ascending/descending flag per compare block.
-
-    Block ``b`` covers flat indices [b*2j, (b+1)*2j); the bitonic direction
-    for a stage whose sorted-run span is ``stage_span = 2**(s+1)`` is
-    ascending iff bit (s+1) of the flat index is 0. Within one block that
-    bit is constant because 2j <= stage_span.
-    """
-    starts = jnp.arange(n_blocks, dtype=jnp.int32) * (2 * j)
-    return (starts // stage_span) % 2 == 0  # True = ascending
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _cmpx(
@@ -58,35 +51,47 @@ def _cmpx(
 
     keys: (R, N). payloads: tuple of (R, N) arrays permuted identically.
     tiebreak: index into payloads used as a lexicographic tie-break
-    (-1 = none). The swap is computed once on keys and broadcast.
+    (-1 = none). Lane ``i`` pairs with lane ``i ^ j``; the pair sorts
+    ascending iff bit ``stage_span`` of ``i`` is 0 (``stage_span`` is the
+    length of the runs this stage builds, constant within a pair because
+    2j <= stage_span). Each lane keeps its own element or takes its
+    partner's, so both lanes of a pair reach the same decision.
     """
     rows, n = keys.shape
-    n_blocks = n // (2 * j)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1)
+    # the low lane of an ascending pair keeps the smaller element, the
+    # high lane the larger; a descending pair the other way round. Low
+    # and ascending are bits j and stage_span of the lane index, so
+    # "keep the smaller" is their XOR being 0 (integer math: Mosaic
+    # cannot compare two boolean vectors)
+    jb, sb = j.bit_length() - 1, stage_span.bit_length() - 1
+    keep_small = (((lane >> jb) ^ (lane >> sb)) & 1) == 0
+    if 2 * j == n:  # both rotations are the same
+        partner = lambda x: pltpu.roll(x, j, 1)
+    else:
+        # which of the two rotations brings lane i ^ j to lane i, read off
+        # a rotated lane index, so the choice holds for either direction
+        from_up = pltpu.roll(lane, n - j, 1) == (lane ^ j)
 
-    def split(x):
-        x4 = x.reshape(rows, n_blocks, 2, j)
-        return x4[:, :, 0, :], x4[:, :, 1, :]
+        def partner(x):
+            return jnp.where(from_up, pltpu.roll(x, n - j, 1), pltpu.roll(x, j, 1))
 
-    def fuse(lo, hi):
-        return jnp.stack([lo, hi], axis=2).reshape(rows, n)
-
-    klo, khi = split(keys)
-    asc = _dir_mask(n_blocks, j, stage_span)[None, :, None]
-
-    gt = klo > khi
-    lt = klo < khi
+    pk = partner(keys)
+    p_lt = pk < keys
+    p_gt = pk > keys
     if tiebreak >= 0:
-        tlo, thi = split(payloads[tiebreak])
-        eq = klo == khi
-        gt = gt | (eq & (tlo > thi))
-        lt = lt | (eq & (tlo < thi))
-    swap = jnp.where(asc, gt, lt)
+        t = payloads[tiebreak]
+        pt = partner(t)
+        eq = pk == keys
+        p_lt = p_lt | (eq & (pt < t))
+        p_gt = p_gt | (eq & (pt > t))
+    take = (keep_small & p_lt) | (~keep_small & p_gt)
 
-    new_keys = fuse(jnp.where(swap, khi, klo), jnp.where(swap, klo, khi))
+    new_keys = jnp.where(take, pk, keys)
     new_payloads = []
-    for p in payloads:
-        plo, phi = split(p)
-        new_payloads.append(fuse(jnp.where(swap, phi, plo), jnp.where(swap, plo, phi)))
+    for i, p in enumerate(payloads):
+        pp = pt if i == tiebreak else partner(p)
+        new_payloads.append(jnp.where(take, pp, p))
     return new_keys, tuple(new_payloads)
 
 
@@ -132,14 +137,15 @@ def _sort_kv_kernel(k_ref, v_ref, ok_ref, ov_ref, *, stable: bool):
 
 
 def _merge_kernel(a_ref, b_ref, o_ref):
-    keys = jnp.concatenate([a_ref[...], b_ref[...][:, ::-1]], axis=-1)
+    # b_ref holds b reversed, so a ++ b_ref is bitonic
+    keys = jnp.concatenate([a_ref[...], b_ref[...]], axis=-1)
     keys, _ = _merge_network(keys, (), tiebreak=-1)
     o_ref[...] = keys
 
 
 def _merge_kv_kernel(ak_ref, av_ref, bk_ref, bv_ref, ok_ref, ov_ref, *, stable: bool):
-    keys = jnp.concatenate([ak_ref[...], bk_ref[...][:, ::-1]], axis=-1)
-    vals = jnp.concatenate([av_ref[...], bv_ref[...][:, ::-1]], axis=-1)
+    keys = jnp.concatenate([ak_ref[...], bk_ref[...]], axis=-1)
+    vals = jnp.concatenate([av_ref[...], bv_ref[...]], axis=-1)
     # stable=True makes the comparator lexicographic in (key, value); when
     # values are unique global indices (dispatch use-case) this is exactly a
     # stable merge, and the runs stay lexicographically sorted inductively.
@@ -156,10 +162,12 @@ def _merge_kv_kernel(ak_ref, av_ref, bk_ref, bv_ref, ok_ref, ov_ref, *, stable: 
 _BLOCK_ROWS = 8
 
 
-def _row_grid_call(kernel, n_in: int, n_out_cols: int, out_dtypes, rows: int, n: int):
-    """Common pallas_call builder: 1-D grid over row blocks, full rows in VMEM."""
-    grid = (max(1, rows // _BLOCK_ROWS),)
+def _row_grid_call(n_in: int, n_out_cols: int, out_dtypes, rows: int, n: int):
+    """Common pallas_call builder: 1-D grid over row blocks, full rows in VMEM.
+    A partial last block (rows % 8 != 0) reads padding rows whose results
+    are never written back."""
     br = min(_BLOCK_ROWS, rows)
+    grid = (pl.cdiv(rows, br),)
     in_specs = [pl.BlockSpec((br, n), lambda i: (i, 0)) for _ in range(n_in)]
     out_specs = [pl.BlockSpec((br, n_out_cols), lambda i: (i, 0)) for _ in out_dtypes]
     out_shape = [jax.ShapeDtypeStruct((rows, n_out_cols), d) for d in out_dtypes]
@@ -169,11 +177,11 @@ def _row_grid_call(kernel, n_in: int, n_out_cols: int, out_dtypes, rows: int, n:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def bitonic_sort_rows(keys: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def bitonic_sort_rows(keys: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """Sort each row of ``keys`` (R, N) ascending. N must be a power of 2."""
     rows, n = keys.shape
     grid, in_specs, out_specs, out_shape = _row_grid_call(
-        _sort_kernel, 1, n, [keys.dtype], rows, n
+        1, n, [keys.dtype], rows, n
     )
     return pl.pallas_call(
         _sort_kernel,
@@ -191,14 +199,14 @@ def bitonic_sort_rows_kv(
     values: jnp.ndarray,
     *,
     stable: bool = True,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Key/value row sort. ``stable=True`` tie-breaks on values, which gives
     a stable sort whenever values are the original indices (the MoE dispatch
     use-case) and a deterministic total order otherwise."""
     rows, n = keys.shape
     grid, in_specs, out_specs, out_shape = _row_grid_call(
-        _sort_kv_kernel, 2, n, [keys.dtype, values.dtype], rows, n
+        2, n, [keys.dtype, values.dtype], rows, n
     )
     return pl.pallas_call(
         functools.partial(_sort_kv_kernel, stable=stable),
@@ -212,12 +220,13 @@ def bitonic_sort_rows_kv(
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bitonic_merge_rows(
-    a: jnp.ndarray, b: jnp.ndarray, *, interpret: bool = True
+    a: jnp.ndarray, b: jnp.ndarray, *, interpret: bool
 ) -> jnp.ndarray:
     """Merge row-wise sorted (R, N) + (R, N) -> sorted (R, 2N)."""
     rows, n = a.shape
+    b = jnp.flip(b, axis=-1)
     grid, in_specs, out_specs, out_shape = _row_grid_call(
-        _merge_kernel, 2, 2 * n, [a.dtype], rows, n
+        2, 2 * n, [a.dtype], rows, n
     )
     return pl.pallas_call(
         _merge_kernel,
@@ -237,11 +246,12 @@ def bitonic_merge_rows_kv(
     bv: jnp.ndarray,
     *,
     stable: bool = True,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     rows, n = ak.shape
+    bk, bv = jnp.flip(bk, axis=-1), jnp.flip(bv, axis=-1)
     grid, in_specs, out_specs, out_shape = _row_grid_call(
-        _merge_kv_kernel, 4, 2 * n, [ak.dtype, av.dtype], rows, n
+        4, 2 * n, [ak.dtype, av.dtype], rows, n
     )
     return pl.pallas_call(
         functools.partial(_merge_kv_kernel, stable=stable),

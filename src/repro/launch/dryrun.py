@@ -32,7 +32,7 @@ from repro.models.model import Model, abstract_params
 from repro.optim import adamw as opt_lib
 from repro.serve.engine import make_prefill, make_serve_step
 from repro.sharding import rules
-from repro.sharding.spec import from_mesh, set_mesh_compat
+from repro.sharding.spec import from_mesh
 from repro.train.step import TrainConfig, make_train_step
 
 
@@ -127,7 +127,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, verbose: bool = True,
     pspecs = rules.param_specs(aparams, cfg, axes, mode="decode" if kind == "decode" else "train")
     t0 = time.time()
 
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             tcfg = TrainConfig(
                 opt=opt_lib.OptConfig(

@@ -22,7 +22,7 @@ from repro.launch.mesh import make_mesh_for
 from repro.models.model import Model
 from repro.optim.adamw import OptConfig
 from repro.sharding import rules
-from repro.sharding.spec import from_mesh, set_mesh_compat
+from repro.sharding.spec import from_mesh
 from repro.train.step import TrainConfig, init_train_state, make_train_step
 
 
@@ -78,7 +78,7 @@ def main(argv=None):
     step_fn = make_train_step(model, tcfg)
     if mesh is not None:
         pspecs = rules.param_specs(jax.eval_shape(lambda: params), cfg, axes)
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             step_fn = jax.jit(step_fn, donate_argnums=(0, 1))
     else:
         step_fn = jax.jit(step_fn, donate_argnums=(0, 1))

@@ -35,7 +35,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import keyenc
 from repro.core.merge import merge_padded_runs_kv
 from repro.models.layers import _init, _act
-from repro.sharding.spec import Axes, axis_size_compat, shard_map_compat
+from repro.sharding.spec import Axes
 
 
 def init_moe(key, cfg, axes, stack=()):
@@ -163,8 +163,8 @@ def _make_a2a(axis_names, hierarchical: bool = False):
         a1, a2 = axis_names
 
         def a2a(x):
-            s1 = axis_size_compat(a1)
-            s2 = axis_size_compat(a2)
+            s1 = jax.lax.axis_size(a1)
+            s2 = jax.lax.axis_size(a2)
             y = x.reshape((s1, s2) + x.shape[1:])
             y = jax.lax.all_to_all(y, a1, split_axis=0, concat_axis=0, tiled=True)
             y = jax.lax.all_to_all(y, a2, split_axis=1, concat_axis=1, tiled=True)
@@ -181,7 +181,7 @@ def _make_a2a(axis_names, hierarchical: bool = False):
 def _shard_index(axis_names) -> jnp.ndarray:
     idx = jnp.int32(0)
     for a in axis_names:
-        idx = idx * axis_size_compat(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -209,7 +209,10 @@ def moe_forward(x, p, cfg, axes: Axes | None, *, use_pallas: bool = False,
 
     enames = axes.expert
     n_shards = axes.expert_size
-    mesh = axes.mesh
+    # inside a traced step the ambient mesh (whose axis types the caller
+    # may have switched, e.g. by jax.sharding.auto_axes) wins
+    ambient = jax.sharding.get_abstract_mesh()
+    mesh = axes.mesh if ambient.empty else ambient
     bax = fit_batch_axes(B, axes)
     # shard the sequence over "model" when possible (token-parallel
     # routing); decode (S == 1) replicates over model instead.
@@ -236,11 +239,12 @@ def moe_forward(x, p, cfg, axes: Axes | None, *, use_pallas: bool = False,
         "wg": P(axes.expert, None, de_ax),
         "wo": P(axes.expert, de_ax, None),
     }
-    f = shard_map_compat(
+    f = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(bax, sax, None), pspec),
         out_specs=(P(bax, sax, None), P()),
+        check_vma=False,
     )
     return f(x, p)
 

@@ -36,9 +36,8 @@ def dequantize_int8(q: jnp.ndarray, scale: jnp.ndarray):
 def compressed_psum_mean(x: jnp.ndarray, axis_name) -> jnp.ndarray:
     """Mean-reduce ``x`` over ``axis_name`` inside shard_map with an int8
     all-gather half. x: flat fp32, length divisible by p*CHUNK."""
-    from repro.sharding.spec import axis_size_compat
 
-    p = axis_size_compat(axis_name)
+    p = jax.lax.axis_size(axis_name)
     part = jax.lax.psum_scatter(x, axis_name, scatter_dimension=0, tiled=True) / p
     q, s = quantize_int8(part)
     qg = jax.lax.all_gather(q, axis_name, tiled=True)

@@ -18,40 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
-def set_mesh_compat(mesh):
-    """Ambient-mesh context manager across jax versions: >= 0.6 has
-    ``jax.set_mesh``; earlier releases use the Mesh object itself as the
-    context manager."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
-def axis_size_compat(axis_name) -> int:
-    """Mesh-axis size inside shard_map, across jax versions: >= 0.5 has
-    ``lax.axis_size``; 0.4.x uses the psum-of-1 idiom (constant-folded to
-    a static int). Accepts a single axis name or a tuple of axes."""
-    axes = axis_name if isinstance(axis_name, (tuple, list)) else (axis_name,)
-    s = 1
-    for a in axes:
-        s *= jax.lax.axis_size(a) if hasattr(jax.lax, "axis_size") else jax.lax.psum(1, a)
-    return s
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: new releases expose it at the
-    top level (replication checking flag ``check_vma``); 0.4.x has it under
-    ``jax.experimental`` with the flag spelled ``check_rep``. Checking is
-    disabled either way — pallas_call bodies don't carry the metadata."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
 @dataclasses.dataclass(frozen=True)
 class Axes:
     batch: tuple[str, ...] = ("data",)  # ("pod","data") multi-pod

@@ -14,6 +14,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.models.model import Model
 from repro.optim import adamw as opt_lib
@@ -69,7 +70,20 @@ def make_train_step(model: Model, tcfg: TrainConfig):
         metrics["lr"] = opt_lib.lr_at(step, tcfg.opt)
         return params, opt_state, metrics
 
-    return train_step
+    def sharded_step(params, opt_state, step, batch):
+        # the model places its activations with sharding constraints
+        # (sharding.spec.constrain), i.e. it is written for auto axes: on a
+        # jax.make_mesh mesh (explicit axes) the step runs with the axes
+        # turned auto, and params/opt_state keep their input shardings
+        if not jax.sharding.get_abstract_mesh().explicit_axes:
+            return train_step(params, opt_state, step, batch)
+        specs = jax.tree.map(lambda a: jax.typeof(a).sharding.spec,
+                             (params, opt_state))
+        out_sharding = (*specs, P())
+        return jax.sharding.auto_axes(train_step, out_sharding=out_sharding)(
+            params, opt_state, step, batch)
+
+    return sharded_step
 
 
 def init_train_state(model: Model, tcfg: TrainConfig, key):

@@ -78,6 +78,43 @@ def test_tile_sort_kv_stable_flat(n, tile):
     np.testing.assert_array_equal(np.asarray(sv), np.asarray(rv[0]))
 
 
+@pytest.mark.parametrize("op", ["sort", "sort_kv", "merge"])
+def test_partial_last_row_block(op):
+    """12 rows = one full 8-row block + a partial one: rows 8-11 must be
+    written too (the grid used to round the block count down)."""
+    k = _rand((12, 16), jnp.int32)
+    v = _rand((12, 16), jnp.int32)
+    if op == "sort":
+        got, want = ops.sort_rows(k), ref.sort_rows_ref(k)
+    elif op == "sort_kv":
+        got = ops.sort_rows_kv(k, jnp.tile(jnp.arange(16, dtype=jnp.int32), (12, 1)))
+        want = ref.sort_rows_kv_ref(k, jnp.tile(jnp.arange(16, dtype=jnp.int32), (12, 1)))
+    else:
+        a, b = jnp.sort(k, axis=-1), jnp.sort(v, axis=-1)
+        got, want = ops.merge_rows(a, b), ref.merge_rows_ref(a, b)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_interpret_only_on_cpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._interpret()
+
+
+def test_kernel_path_names_64bit_fallback():
+    assert ops.kernel_path(jnp.int32, jnp.bfloat16).startswith("Pallas")
+    assert "(int64)" in ops.kernel_path(np.dtype("int64"))
+    assert "(float64)" in ops.kernel_path(jnp.float32, np.dtype("float64"))
+    import repro
+
+    reasons = repro.plan(np.arange(8, dtype=np.int32)).reasons
+    assert any("Pallas bitonic kernels" in r for r in reasons)
+
+
 def test_lax_fallback_path_equivalence():
     x = _rand((6000,), jnp.float32)
     a = ops.tile_sort(x, tile=512, use_pallas=True)

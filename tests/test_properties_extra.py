@@ -88,7 +88,7 @@ def test_elastic_remesh_lowers_on_shrunk_device_set():
         from repro.launch.mesh import make_mesh_for
         from repro.models.model import Model
         from repro.optim.adamw import OptConfig
-        from repro.sharding.spec import from_mesh, set_mesh_compat
+        from repro.sharding.spec import from_mesh
         from repro.train.step import TrainConfig, make_train_step, init_train_state
 
         cfg = smoke_config("qwen3-4b")
@@ -100,7 +100,7 @@ def test_elastic_remesh_lowers_on_shrunk_device_set():
             params, opt = init_train_state(m, tcfg, jax.random.key(0))
             batch = {"tokens": jnp.zeros((1, 4, 32), jnp.int32),
                      "labels": jnp.zeros((1, 4, 32), jnp.int32)}
-            with set_mesh_compat(mesh):
+            with jax.set_mesh(mesh):
                 c = jax.jit(make_train_step(m, tcfg)).lower(
                     params, opt, jnp.int32(0), batch).compile()
             print("lowered on", n, "devices:", mesh.devices.shape)

@@ -15,6 +15,7 @@ from repro.core import (
     investigator_bounds,
     load_imbalance,
     naive_bounds,
+    regular_sample,
     sample_sort_sim,
     select_splitters,
 )
@@ -71,6 +72,16 @@ def test_investigator_beats_naive_on_duplicates():
     assert float(load_imbalance(inv.counts)) < 1.01
     assert float(load_imbalance(naive.counts)) > 1.3
     assert int(naive.counts.min()) == 0  # starved processors (Fig. 3b)
+
+
+def test_regular_sample_strides_past_int32():
+    """A 2^23-key shard with 2048 samples: the stride products pass 2^31.
+    Wrapped indices sampled the wrong keys, so splitters went bad and a
+    2^26-key sort ran ~4.5x imbalanced through two capacity retries."""
+    n, s = 1 << 23, 2048
+    got = np.asarray(regular_sample(jnp.arange(n, dtype=jnp.int32), s))
+    want = ((2 * np.arange(s, dtype=np.int64) + 1) * n) // (2 * s)
+    np.testing.assert_array_equal(got, want)
 
 
 def test_order_across_processors():
