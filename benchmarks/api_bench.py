@@ -318,8 +318,8 @@ def trace_overhead():
     tr = out.meta.trace
     assert tr is not None and tr.frozen, "trace=True sort must attach a trace"
     names = {s.name for s in tr.spans}
-    for phase in ("plan", "encode", "stage", "local_sort", "splitter",
-                  "exchange", "merge", "decode", "d2h"):
+    for phase in ("plan", "encode", "stage", "sort", "dispatch",
+                  "overflow_check", "decode", "d2h"):
         assert phase in names, f"missing phase span: {phase}"
     cov = tr.coverage()
     emit("api_trace_coverage", tr.duration() * 1e6,

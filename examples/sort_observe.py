@@ -1,8 +1,13 @@
 """Observability tour: phase tracing, Chrome export, metrics scrape,
 request-scoped flight recording, and SLO burn rates.
 
-A traced sort prints its phase table (wall time, per-processor counts,
-per-phase imbalance — the paper's Table II lens, per step), an ambient
+A traced sort prints its phase table (wall time of each host phase, and
+the per-processor counts and imbalance of the sort program — the paper's
+Table II lens). A traced sort runs the same compiled program as an
+untraced one; the per-phase device split of that program (local sort,
+splitter, exchange, merge, decode) is in a ``jax.profiler`` capture,
+where each step is a ``jax.named_scope`` and each host span a
+``repro.<span>`` annotation on the same clock. An ambient
 trace collects a whole block of sorts, the trace exports to a
 chrome://tracing / Perfetto JSON file, and a short burst against the
 async SortServer is scraped through the Prometheus text exposition.
@@ -43,7 +48,9 @@ def main():
     rng = np.random.default_rng(0)
 
     # -- one traced sort: SortLimits(trace=True) attaches the phase
-    #    breakdown to out.meta.trace; it freezes at materialization
+    #    breakdown to out.meta.trace; it freezes at materialization. The
+    #    "sort" span is the fused program (dispatch + overflow_check
+    #    inside it), fenced, with per-processor counts
     x = rng.normal(0, 1, 1 << 18).astype(np.float32)
     out = repro.sort(x, config=cfg,
                      limits=repro.SortLimits(trace=True,

@@ -175,15 +175,22 @@ Observability (``repro.obs``)
 -----------------------------
 Phase-level tracing: ``repro.sort(x, limits=SortLimits(trace=True))``
 attaches a ``Trace`` to ``out.meta.trace`` recording one wall-time span
-per pipeline phase — ``plan``, ``encode`` (key encode / multi-key
-pack), ``stage`` (H2D), ``local_sort``, ``splitter``, ``exchange``,
-``merge``, ``decode``, ``d2h`` — with ``jax.block_until_ready`` fencing
-so device work is charged to the phase that dispatched it (a traced
-sim/mesh sort runs as separately-jitted phase programs; the untraced
-hot path keeps the fused program). Spans carry per-processor counts and
-the max/mean ``imbalance`` per phase (paper Table II, per step). The
-trace freezes — becomes immutable and publishes its spans to the
-``repro_sort_phase_seconds`` histogram — when the output materializes.
+per host phase — ``plan``, ``encode`` (key encode / multi-key pack),
+``stage`` (H2D), ``sort`` (the fused program: ``dispatch`` enqueues it,
+``overflow_check`` waits for its overflow flag), ``decode``, ``d2h`` —
+with ``jax.block_until_ready`` fencing so device work is charged to the
+phase that dispatched it. A traced sim/mesh sort runs the same compiled
+program as an untraced one; the ``sort`` span carries its per-processor
+counts and their max/mean ``imbalance`` (paper Table II). The stream
+backend, whose passes are separate programs, records ``local_sort``,
+``splitter`` and ``merge`` spans per pass. The device split of the
+fused program is in a ``jax.profiler`` capture: each paper step runs
+under a ``jax.named_scope`` (``local_sort``, ``splitter``,
+``exchange``, ``merge``, ``decode``; ``obs.tracing.PHASES``) and every
+span is also a ``repro.<span>`` ``TraceAnnotation``, so host phases and
+device phases share the profile's clock. The trace freezes — becomes
+immutable and publishes its spans to the ``repro_sort_phase_seconds``
+histogram — when the output materializes.
 ``with obs.trace(job="nightly") as tr:`` installs an ambient trace that
 collects every sort in the block instead. ``tr.phase_totals()``,
 ``tr.coverage()``, and ``tr.to_chrome_file(path)`` (Chrome/Perfetto
@@ -199,9 +206,7 @@ latency histograms), the shared program cache
 ``tests/metrics_schema.json`` pins the metric names/label sets in CI.
 ``obs.disabled()`` / ``obs.set_enabled(False)`` turn the whole
 subsystem off (the ``trace_overhead`` gate holds its residue under 2%).
-``REPRO_PROFILE=1`` additionally brackets flush programs and stream
-chunk staging with ``jax.profiler`` annotations. Runnable tour:
-``examples/sort_observe.py``.
+Runnable tour: ``examples/sort_observe.py``.
 
 Request tracing + flight recorder (``repro.obs.flight``): every
 serve-tier request is minted a ``trace_id`` at ``SortServer.submit()``

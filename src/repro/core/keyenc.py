@@ -714,21 +714,22 @@ def decode_grid(keys_grid, counts, values_grid=None, *, m: int,
     from repro.core.local_sort import segment_stable_kv
     from repro.kernels.ops import sentinel_for
 
-    keys_grid, counts, values_grid = (
-        _replicated(x) for x in (keys_grid, counts, values_grid))
-    ks = compact_rows(keys_grid, counts, m)
-    vs = None
-    if values_grid is not None:
-        vs = compact_rows(values_grid, counts, m)
-        if want_order:
-            total = jnp.sum(jnp.asarray(counts).astype(jnp.int32))
-            valid = jnp.arange(m, dtype=jnp.int32) < total
-            vs = segment_stable_kv(
-                jnp.where(valid, ks, sentinel_for(ks.dtype)),
-                jnp.where(valid, vs, sentinel_for(vs.dtype)),
-            )
-    if descending:
-        ks = flip(ks)
-    if packspec is not None:
-        ks = unpack_fields(ks, packspec)
+    with jax.named_scope("decode"):
+        keys_grid, counts, values_grid = (
+            _replicated(x) for x in (keys_grid, counts, values_grid))
+        ks = compact_rows(keys_grid, counts, m)
+        vs = None
+        if values_grid is not None:
+            vs = compact_rows(values_grid, counts, m)
+            if want_order:
+                total = jnp.sum(jnp.asarray(counts).astype(jnp.int32))
+                valid = jnp.arange(m, dtype=jnp.int32) < total
+                vs = segment_stable_kv(
+                    jnp.where(valid, ks, sentinel_for(ks.dtype)),
+                    jnp.where(valid, vs, sentinel_for(vs.dtype)),
+                )
+        if descending:
+            ks = flip(ks)
+        if packspec is not None:
+            ks = unpack_fields(ks, packspec)
     return ks, vs

@@ -17,9 +17,10 @@ from repro.kernels import ops as kops
 
 def local_sort(x: jnp.ndarray, *, tile: int = 1024, use_pallas: bool = True) -> jnp.ndarray:
     """Sort a flat local shard ascending."""
-    if not use_pallas:
-        return jnp.sort(x)
-    return kops.tile_sort(x, tile=tile, use_pallas=True)
+    with jax.named_scope("local_sort"):
+        if not use_pallas:
+            return jnp.sort(x)
+        return kops.tile_sort(x, tile=tile, use_pallas=True)
 
 
 def local_sort_kv(
@@ -33,10 +34,13 @@ def local_sort_kv(
     """Sort (keys, values) by key. Stable when values are unique indices
     (always true for the provenance/dispatch paths); for arbitrary values
     the caller wraps with an index payload first (see api.sort_kv)."""
-    if not use_pallas:
-        k, v = jax.lax.sort([keys, values], dimension=0, is_stable=stable, num_keys=1)
-        return k, v
-    return kops.tile_sort_kv(keys, values, tile=tile, stable=stable, use_pallas=True)
+    with jax.named_scope("local_sort"):
+        if not use_pallas:
+            k, v = jax.lax.sort([keys, values], dimension=0, is_stable=stable,
+                                num_keys=1)
+            return k, v
+        return kops.tile_sort_kv(keys, values, tile=tile, stable=stable,
+                                 use_pallas=True)
 
 
 def segment_stable_kv(keys: jnp.ndarray, values: jnp.ndarray) -> jnp.ndarray:

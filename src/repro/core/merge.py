@@ -9,6 +9,7 @@ compacted until the very end.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
@@ -31,7 +32,8 @@ def merge_padded_runs(runs: jnp.ndarray, *, use_pallas: bool = True) -> jnp.ndar
     Sentinel padding (+inf / INT_MAX) must already sit at each row's tail.
     """
     fill = kops.sentinel_for(runs.dtype)
-    return kops.merge_tree(_pad_runs_pow2(runs, fill), use_pallas=use_pallas)
+    with jax.named_scope("merge"):
+        return kops.merge_tree(_pad_runs_pow2(runs, fill), use_pallas=use_pallas)
 
 
 def merge_padded_runs_kv(
@@ -44,6 +46,7 @@ def merge_padded_runs_kv(
     """Key/value variant; the value payload rides the same permutation."""
     kfill = kops.sentinel_for(keys.dtype)
     vfill = kops.sentinel_for(values.dtype)
-    return kops.merge_tree_kv(_pad_runs_pow2(keys, kfill),
-                              _pad_runs_pow2(values, vfill),
-                              stable=stable, use_pallas=use_pallas)
+    with jax.named_scope("merge"):
+        return kops.merge_tree_kv(_pad_runs_pow2(keys, kfill),
+                                  _pad_runs_pow2(values, vfill),
+                                  stable=stable, use_pallas=use_pallas)

@@ -154,11 +154,12 @@ class SortLimits:
       encode, stage, local sort, splitter, exchange, merge, decode, D2H)
       on ``SortOutput.meta.trace`` — a ``repro.obs.tracing.Trace`` with
       per-processor counts, per-phase imbalance, and Chrome trace-event
-      export. The sim and (keys-only) mesh backends run the sort as
-      separately fenced phase programs under tracing, so the breakdown
-      is real wall time per phase, not dispatch time. Default False:
-      the untraced hot path is unchanged. An ambient ``obs.trace()``
-      block traces regardless of this flag.
+      export. The sim and mesh backends run the same fused program as
+      an untraced sort, under one fenced ``sort`` span; its per-phase
+      device split (local sort, splitter, exchange, merge, decode) is
+      in a ``jax.profiler`` capture, as ``jax.named_scope`` phases on
+      the same clock as the spans. Default False. An ambient
+      ``obs.trace()`` block traces regardless of this flag.
     x64: per-request x64-mode override (see ``core.x64``). None
       (default) follows the ambient switch (``repro.enable_x64()`` /
       ``REPRO_X64=1``); True admits 64-bit keys/values for THIS request
@@ -854,6 +855,45 @@ def _measured_hook(p: int, n_local: int):
     return measured_capacity_need(p, n_local)
 
 
+def _sort_fused(req: _Req, plan: SortPlan, backend: str, program: Callable,
+                counts_of: Callable, p: int, n_local: int, pad: int) -> SortOutput:
+    """Run an in-core backend's one fused sort program through the
+    overflow ladder, traced or not; the caller adds the decode.
+
+    ``dispatch`` enqueues an attempt and ``overflow_check`` is the
+    host's wait on its overflow flag and send counts: the program's
+    outputs are ready together, so this is where the host waits out the
+    sort. A trace gets one fenced ``sort`` span around the ladder, with
+    the result's per-processor counts and their imbalance; the program's
+    per-phase device split is in a profile, under its
+    ``jax.named_scope`` phases."""
+    tr = req.trace
+
+    def attempt(cfg):
+        with _span(tr, "dispatch"):
+            res = program(cfg)
+        with _span(tr, "overflow_check"):
+            np.asarray(res.overflowed)
+            np.asarray(res.send_counts)
+        return res
+
+    with _span(tr, "sort", phases="local_sort+splitter+exchange+merge") as sp:
+        res, cfg_used, retries = run_with_capacity_retry(
+            attempt, req.config, plan.limits.policy(),
+            measured=_measured_hook(p, n_local),
+        )
+        out = SortOutput(
+            _meta(req, plan, backend, cfg_used, retries),
+            counts=_trim_pad_counts(counts_of(res), pad),
+            overflowed=bool(np.any(np.asarray(res.overflowed))),
+            send_counts=np.asarray(res.send_counts),
+            raw=res,
+        )
+        sp.fence(res)
+        sp.counts(out.counts)
+    return out
+
+
 def _exec_sim(req: _Req, plan: SortPlan) -> SortOutput:
     import jax.numpy as jnp
 
@@ -888,41 +928,22 @@ def _exec_sim(req: _Req, plan: SortPlan) -> SortOutput:
         if tr is not None:
             sp.fence((xk, xv))  # charge the H2D copy to staging
 
-    if tr is not None:
-        # traced: the four-phase programs, one fenced span each
-        if xv is None:
-            run = lambda cfg: sim.sample_sort_sim_phased(
-                xk, cfg, investigator=req.investigator, trace=tr
-            )
-        else:
-            run = lambda cfg: sim.sample_sort_sim_phased_kv(
-                xk, xv, cfg, investigator=req.investigator, trace=tr
-            )
-    elif xv is None:
-        run = lambda cfg: sim.sample_sort_sim(
+    if xv is None:
+        program = lambda cfg: sim.sample_sort_sim(
             xk, cfg, investigator=req.investigator
         )
     else:
-        run = lambda cfg: sim.sample_sort_sim_kv(
+        program = lambda cfg: sim.sample_sort_sim_kv(
             xk, xv, cfg, investigator=req.investigator
         )
-    res, cfg_used, retries = run_with_capacity_retry(
-        run, req.config, plan.limits.policy(),
-        measured=_measured_hook(p, int(xk.shape[1])),
-    )
-
+    out = _sort_fused(req, plan, "sim", program, lambda r: r.counts, p,
+                      int(xk.shape[1]), pad)
+    # the decode program is dispatched last, so its span ends the call
+    res = out.raw
     kg, vg = (res.values, None) if xv is None else (res.keys, res.values)
-    materialize = _grid_materialize(req, plan, kg, vg, res.counts, m,
-                                    descending, reverse)
-    meta = _meta(req, plan, "sim", cfg_used, retries)
-    return SortOutput(
-        meta,
-        counts=_trim_pad_counts(res.counts, pad),
-        overflowed=bool(np.any(np.asarray(res.overflowed))),
-        send_counts=np.asarray(res.send_counts),
-        raw=res,
-        materialize=materialize,
-    )
+    out._materialize = _grid_materialize(req, plan, kg, vg, res.counts, m,
+                                         descending, reverse)
+    return out
 
 
 def _exec_mesh(req: _Req, plan: SortPlan) -> SortOutput:
@@ -963,51 +984,25 @@ def _exec_mesh(req: _Req, plan: SortPlan) -> SortOutput:
         if tr is not None:
             sp.fence((xk, xv))
 
-    if tr is not None and xv is None:
-        # traced keys-only: four fenced phase programs (sample_sort.py)
-        run = lambda cfg: sample_sort.distributed_sort_phased(
-            xk, plan.mesh, plan.axis_name, cfg,
-            investigator=req.investigator, trace=tr,
-        )
-    elif xv is None:
-        run = lambda cfg: sample_sort.distributed_sort(
+    if xv is None:
+        program = lambda cfg: sample_sort.distributed_sort(
             xk, plan.mesh, plan.axis_name, cfg, investigator=req.investigator
         )
     else:
-        run = lambda cfg: sample_sort.distributed_sort_kv(
+        program = lambda cfg: sample_sort.distributed_sort_kv(
             xk, xv, plan.mesh, plan.axis_name, cfg, investigator=req.investigator
         )
-    if tr is not None and xv is not None:
-        # kv mesh sorts keep the fused program: one "sort" span covering
-        # local_sort+splitter+exchange+merge, fenced, per-device counts
-        fused = run
-
-        def run(cfg):
-            with tr.span("sort", phases="local_sort+splitter+exchange+merge") as sp:
-                res = sp.fence(fused(cfg))
-                sp.counts(np.asarray(res.count).tolist())
-            return res
-
-    res, cfg_used, retries = run_with_capacity_retry(
-        run, req.config, plan.limits.policy(),
-        measured=_measured_hook(p, per),
-    )
-
-    kg, vg = (res.values, None) if xv is None else (res.keys, res.values)
+    out = _sort_fused(req, plan, "mesh", program, lambda r: r.count, p, per,
+                      pad)
     # the rows are sharded over the sort axis; the device decode gathers
-    # them under this mesh (keyenc.decode_grid)
+    # them under this mesh (keyenc.decode_grid), dispatched last so that
+    # its span ends the call
+    res = out.raw
+    kg, vg = (res.values, None) if xv is None else (res.keys, res.values)
     with jax.set_mesh(plan.mesh):
-        materialize = _grid_materialize(req, plan, kg, vg, res.count, m,
-                                        descending, reverse)
-    meta = _meta(req, plan, "mesh", cfg_used, retries)
-    return SortOutput(
-        meta,
-        counts=_trim_pad_counts(res.count, pad),
-        overflowed=bool(np.any(np.asarray(res.overflowed))),
-        send_counts=np.asarray(res.send_counts),
-        raw=res,
-        materialize=materialize,
-    )
+        out._materialize = _grid_materialize(req, plan, kg, vg, res.count, m,
+                                             descending, reverse)
+    return out
 
 
 def _exec_stream(req: _Req, plan: SortPlan) -> SortOutput:
@@ -1380,7 +1375,7 @@ def execute(keys, values=None, *, order="asc", want="values", where=None,
         req = _normalize(keys, values, order=order, want=want, config=config,
                          investigator=investigator, x64=eff_x64)
         plan = _make_plan(req, where, lim, x64=eff_x64)
-    if tr is not None:
-        tr.labels.setdefault("backend", plan.backend)
-        req.trace = tr
+        if tr is not None:
+            tr.labels.setdefault("backend", plan.backend)
+            req.trace = tr
     return execute_request(req, plan)

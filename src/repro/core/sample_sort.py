@@ -9,7 +9,9 @@ collectives (DESIGN.md §2 mapping):
 
 The local math — tile sort, regular sampling, splitter selection,
 investigator bounds, balanced pairwise merge — is shared with the
-virtual-processor simulator (``sim.py``) which doubles as its oracle.
+virtual-processor simulator (``sim.py``) which doubles as its oracle,
+and so are the ``jax.named_scope`` phase names (``obs.tracing.PHASES``)
+that split the one fused program's device time in a profile.
 
 The sort axis may be a single mesh axis ("data") or a tuple of axes
 (("pod", "data")) — the multi-pod case: ``lax`` collectives accept axis
@@ -22,7 +24,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import merge as merge_lib
@@ -68,29 +69,32 @@ def sample_sort_shard(
     # (1) local sort
     xs = local_sort(x_local, tile=config.tile, use_pallas=config.use_pallas)
 
-    # (2)+(3) sample -> all_gather -> replicated splitter selection
-    s = config.num_samples(p, n, key_bytes=x_local.dtype.itemsize)
-    samples = spl.regular_sample(xs, s)
-    all_samples = jax.lax.all_gather(samples, axis_name, tiled=True)  # (p*s,)
-    splitters = spl.select_splitters(all_samples, p)
+    with jax.named_scope("splitter"):
+        # (2)+(3) sample -> all_gather -> replicated splitter selection
+        s = config.num_samples(p, n, key_bytes=x_local.dtype.itemsize)
+        samples = spl.regular_sample(xs, s)
+        all_samples = jax.lax.all_gather(samples, axis_name, tiled=True)  # (p*s,)
+        splitters = spl.select_splitters(all_samples, p)
 
-    # (4) investigator binary search
-    bounds = (
-        spl.investigator_bounds(xs, splitters)
-        if investigator
-        else spl.naive_bounds(xs, splitters)
-    )
-    send_counts = bounds[1:] - bounds[:-1]  # (p,)
-    overflowed = jax.lax.pmax(jnp.any(send_counts > cap), axis_name)
+        # (4) investigator binary search
+        bounds = (
+            spl.investigator_bounds(xs, splitters)
+            if investigator
+            else spl.naive_bounds(xs, splitters)
+        )
+        send_counts = bounds[1:] - bounds[:-1]  # (p,)
+        overflowed = jax.lax.pmax(jnp.any(send_counts > cap), axis_name)
 
-    # (5) fused static-capacity exchange
-    fill = kops.sentinel_for(xs.dtype)
-    xs_pad = jnp.concatenate([xs, jnp.full((cap,), fill, xs.dtype)])
-    send = _gather_buckets(xs_pad, bounds, cap, p)  # (p, cap)
-    recv = jax.lax.all_to_all(send, axis_name, split_axis=0, concat_axis=0, tiled=True)
-    recv_counts = jax.lax.all_to_all(
-        send_counts, axis_name, split_axis=0, concat_axis=0, tiled=True
-    )
+    with jax.named_scope("exchange"):
+        # (5) fused static-capacity exchange
+        fill = kops.sentinel_for(xs.dtype)
+        xs_pad = jnp.concatenate([xs, jnp.full((cap,), fill, xs.dtype)])
+        send = _gather_buckets(xs_pad, bounds, cap, p)  # (p, cap)
+        recv = jax.lax.all_to_all(send, axis_name, split_axis=0, concat_axis=0,
+                                  tiled=True)
+        recv_counts = jax.lax.all_to_all(
+            send_counts, axis_name, split_axis=0, concat_axis=0, tiled=True
+        )
 
     # (6) balanced pairwise merge of the p received runs
     merged = merge_lib.merge_padded_runs(recv, use_pallas=config.use_pallas)
@@ -114,29 +118,33 @@ def sample_sort_shard_kv(
         keys_local, values_local, tile=config.tile, use_pallas=config.use_pallas
     )
 
-    s = config.num_samples(p, n, key_bytes=keys_local.dtype.itemsize)
-    samples = spl.regular_sample(ks, s)
-    all_samples = jax.lax.all_gather(samples, axis_name, tiled=True)
-    splitters = spl.select_splitters(all_samples, p)
+    with jax.named_scope("splitter"):
+        s = config.num_samples(p, n, key_bytes=keys_local.dtype.itemsize)
+        samples = spl.regular_sample(ks, s)
+        all_samples = jax.lax.all_gather(samples, axis_name, tiled=True)
+        splitters = spl.select_splitters(all_samples, p)
 
-    bounds = (
-        spl.investigator_bounds(ks, splitters)
-        if investigator
-        else spl.naive_bounds(ks, splitters)
-    )
-    send_counts = bounds[1:] - bounds[:-1]
-    overflowed = jax.lax.pmax(jnp.any(send_counts > cap), axis_name)
+        bounds = (
+            spl.investigator_bounds(ks, splitters)
+            if investigator
+            else spl.naive_bounds(ks, splitters)
+        )
+        send_counts = bounds[1:] - bounds[:-1]
+        overflowed = jax.lax.pmax(jnp.any(send_counts > cap), axis_name)
 
-    kfill = kops.sentinel_for(ks.dtype)
-    vfill = kops.sentinel_for(vs.dtype)
-    ks_pad = jnp.concatenate([ks, jnp.full((cap,), kfill, ks.dtype)])
-    vs_pad = jnp.concatenate([vs, jnp.full((cap,), vfill, vs.dtype)])
-    send_k, send_v = _gather_buckets_kv(ks_pad, vs_pad, bounds, cap, p)
-    recv_k = jax.lax.all_to_all(send_k, axis_name, split_axis=0, concat_axis=0, tiled=True)
-    recv_v = jax.lax.all_to_all(send_v, axis_name, split_axis=0, concat_axis=0, tiled=True)
-    recv_counts = jax.lax.all_to_all(
-        send_counts, axis_name, split_axis=0, concat_axis=0, tiled=True
-    )
+    with jax.named_scope("exchange"):
+        kfill = kops.sentinel_for(ks.dtype)
+        vfill = kops.sentinel_for(vs.dtype)
+        ks_pad = jnp.concatenate([ks, jnp.full((cap,), kfill, ks.dtype)])
+        vs_pad = jnp.concatenate([vs, jnp.full((cap,), vfill, vs.dtype)])
+        send_k, send_v = _gather_buckets_kv(ks_pad, vs_pad, bounds, cap, p)
+        recv_k = jax.lax.all_to_all(send_k, axis_name, split_axis=0, concat_axis=0,
+                                    tiled=True)
+        recv_v = jax.lax.all_to_all(send_v, axis_name, split_axis=0, concat_axis=0,
+                                    tiled=True)
+        recv_counts = jax.lax.all_to_all(
+            send_counts, axis_name, split_axis=0, concat_axis=0, tiled=True
+        )
 
     mk, mv = merge_lib.merge_padded_runs_kv(recv_k, recv_v, use_pallas=config.use_pallas)
     return ShardSortKVResult(mk, mv, recv_counts.sum(), overflowed, send_counts)
@@ -200,106 +208,6 @@ def _mesh_program(mesh, axis_name, config, investigator: bool, kv: bool):
             check_vma=False,
         )
     return jax.jit(f)
-
-
-@functools.lru_cache(maxsize=None)
-def _mesh_phase_programs(mesh, axis_name, config, investigator: bool):
-    """Per-phase shard_map programs for traced mesh sorts (keys-only).
-
-    The fused ``_mesh_program`` keeps communication overlapped with the
-    local merge — the paper's latency-hiding — but is opaque to phase
-    attribution. Traced sorts trade that overlap for the breakdown: the
-    same shard bodies run as four programs (local sort / splitter
-    selection / exchange / merge) so each span fences on its own output.
-    kv mesh sorts keep the fused program under tracing (one "sort" span)
-    — phase splitting both paths is not worth doubling this table."""
-    axes = axis_name if isinstance(axis_name, tuple) else (axis_name,)
-
-    def local_body(xl):
-        xs = local_sort(xl[0], tile=config.tile, use_pallas=config.use_pallas)
-        return xs[None]
-
-    def split_body(xsl):
-        xs = xsl[0]
-        p = jax.lax.axis_size(axis_name)
-        (n,) = xs.shape
-        cap = config.capacity(p, n)
-        s = config.num_samples(p, n, key_bytes=xs.dtype.itemsize)
-        samples = spl.regular_sample(xs, s)
-        all_samples = jax.lax.all_gather(samples, axis_name, tiled=True)
-        splitters = spl.select_splitters(all_samples, p)
-        bounds = (
-            spl.investigator_bounds(xs, splitters)
-            if investigator
-            else spl.naive_bounds(xs, splitters)
-        )
-        send_counts = bounds[1:] - bounds[:-1]
-        overflowed = jax.lax.pmax(jnp.any(send_counts > cap), axis_name)
-        return bounds[None], send_counts[None], overflowed[None]
-
-    def exch_body(xsl, bl):
-        xs, bounds = xsl[0], bl[0]
-        p = jax.lax.axis_size(axis_name)
-        (n,) = xs.shape
-        cap = config.capacity(p, n)
-        fill = kops.sentinel_for(xs.dtype)
-        xs_pad = jnp.concatenate([xs, jnp.full((cap,), fill, xs.dtype)])
-        send = _gather_buckets(xs_pad, bounds, cap, p)
-        recv = jax.lax.all_to_all(
-            send, axis_name, split_axis=0, concat_axis=0, tiled=True
-        )
-        send_counts = bounds[1:] - bounds[:-1]
-        recv_counts = jax.lax.all_to_all(
-            send_counts, axis_name, split_axis=0, concat_axis=0, tiled=True
-        )
-        return recv[None], recv_counts.sum()[None]
-
-    def merge_body(rl):
-        merged = merge_lib.merge_padded_runs(rl[0], use_pallas=config.use_pallas)
-        return merged[None]
-
-    def program(body, in_specs, out_specs):
-        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, check_vma=False))
-
-    local_f = program(local_body, P(axes), P(axes))
-    split_f = program(split_body, P(axes), (P(axes), P(axes), P(axes)))
-    exch_f = program(exch_body, (P(axes), P(axes)), (P(axes), P(axes)))
-    merge_f = program(merge_body, P(axes), P(axes))
-    return local_f, split_f, exch_f, merge_f
-
-
-def distributed_sort_phased(
-    x: jnp.ndarray,
-    mesh: jax.sharding.Mesh,
-    axis_name="data",
-    config: spl.SortConfig = spl.SortConfig(),
-    *,
-    investigator: bool = True,
-    trace,
-) -> ShardSortResult:
-    """Traced mesh sort: same result as ``distributed_sort``, run as four
-    fenced phase programs recording spans on ``trace`` with per-device
-    counts. Each overflow-ladder step appends a fresh set of spans."""
-    p = _axis_product(mesh, axis_name)
-    local_f, split_f, exch_f, merge_f = _mesh_phase_programs(
-        mesh, axis_name, config, investigator
-    )
-    xg = x.reshape(p, -1)
-    n = xg.shape[1]
-    with trace.span("local_sort") as sp:
-        xs = sp.fence(local_f(xg))
-        sp.counts([n] * p)
-    with trace.span("splitter") as sp:
-        bounds, send_counts, overflowed = sp.fence(split_f(xs))
-        sp.set(overflowed=bool(jnp.any(overflowed)))
-    with trace.span("exchange") as sp:
-        recv, counts = sp.fence(exch_f(xs, bounds))
-        sp.counts(np.asarray(counts).tolist())
-    with trace.span("merge") as sp:
-        merged = sp.fence(merge_f(recv))
-        sp.counts(np.asarray(counts).tolist())
-    return ShardSortResult(merged, counts, overflowed, send_counts)
 
 
 def _axis_product(mesh, axis_name) -> int:

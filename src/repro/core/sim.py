@@ -8,7 +8,10 @@ shard_map implementation in ``sample_sort.py`` shares all the local math
 (splitters, investigator, merge tree) and differs only in using real
 ``jax.lax`` collectives.
 
-The six paper steps map 1:1 onto the code below.
+The six paper steps map 1:1 onto the code below, each under a
+``jax.named_scope`` named by ``repro.obs.tracing.PHASES`` (local sort and
+merge scope themselves in ``local_sort.py`` / ``merge.py``), so a device
+profile splits the fused program's time by phase.
 """
 from __future__ import annotations
 
@@ -122,22 +125,24 @@ def sample_sort_sim(
     # (1) local sort — Fig. 2 tile sort + balanced merge tree per shard
     xs = jax.vmap(lambda r: local_sort(r, tile=config.tile, use_pallas=config.use_pallas))(x)
 
-    # (2) buffer-sized regular sampling; (3) replicated splitter selection
-    s = config.num_samples(p, n, key_bytes=x.dtype.itemsize)
-    samples = jax.vmap(lambda r: spl.regular_sample(r, s))(xs)  # "send to master"
-    splitters = spl.select_splitters(samples.reshape(-1), p)
+    with jax.named_scope("splitter"):
+        # (2) buffer-sized regular sampling; (3) replicated splitter selection
+        s = config.num_samples(p, n, key_bytes=x.dtype.itemsize)
+        samples = jax.vmap(lambda r: spl.regular_sample(r, s))(xs)  # "send to master"
+        splitters = spl.select_splitters(samples.reshape(-1), p)
 
-    # (4) investigator binary search -> destination bounds per shard
-    bounds = _bounds_all(xs, splitters, investigator)  # (p, p+1)
-    send_counts = bounds[:, 1:] - bounds[:, :-1]  # (p, p)
-    overflowed = jnp.any(send_counts > cap)
+        # (4) investigator binary search -> destination bounds per shard
+        bounds = _bounds_all(xs, splitters, investigator)  # (p, p+1)
+        send_counts = bounds[:, 1:] - bounds[:, :-1]  # (p, p)
+        overflowed = jnp.any(send_counts > cap)
 
-    # (5) exchange — static-capacity buckets, transpose = all_to_all
-    fill = kops.sentinel_for(xs.dtype)
-    xs_pad = jnp.concatenate([xs, jnp.full((p, cap), fill, xs.dtype)], axis=1)
-    send = jax.vmap(lambda row, b: _gather_buckets(row, b, cap, p))(xs_pad, bounds)
-    recv = jnp.swapaxes(send, 0, 1)  # (p_dst, p_src, cap)
-    counts = send_counts.T.sum(axis=1)  # (p_dst,)
+    with jax.named_scope("exchange"):
+        # (5) exchange — static-capacity buckets, transpose = all_to_all
+        fill = kops.sentinel_for(xs.dtype)
+        xs_pad = jnp.concatenate([xs, jnp.full((p, cap), fill, xs.dtype)], axis=1)
+        send = jax.vmap(lambda row, b: _gather_buckets(row, b, cap, p))(xs_pad, bounds)
+        recv = jnp.swapaxes(send, 0, 1)  # (p_dst, p_src, cap)
+        counts = send_counts.T.sum(axis=1)  # (p_dst,)
 
     # (6) balanced pairwise merge of the received runs
     merged = jax.vmap(
@@ -168,79 +173,16 @@ def sample_sort_sim_kv(
         lambda k, v: local_sort_kv(k, v, tile=config.tile, use_pallas=config.use_pallas)
     )(keys, values)
 
-    s = config.num_samples(p, n, key_bytes=keys.dtype.itemsize)
-    samples = jax.vmap(lambda r: spl.regular_sample(r, s))(ks)
-    splitters = spl.select_splitters(samples.reshape(-1), p)
-
-    bounds = _bounds_all(ks, splitters, investigator)
-    send_counts = bounds[:, 1:] - bounds[:, :-1]
-    overflowed = jnp.any(send_counts > cap)
-
-    kfill = kops.sentinel_for(ks.dtype)
-    vfill = kops.sentinel_for(vs.dtype)
-    ks_pad = jnp.concatenate([ks, jnp.full((p, cap), kfill, ks.dtype)], axis=1)
-    vs_pad = jnp.concatenate([vs, jnp.full((p, cap), vfill, vs.dtype)], axis=1)
-    send_k, send_v = jax.vmap(
-        lambda kk, vv, b: _gather_buckets_kv(kk, vv, b, cap, p)
-    )(ks_pad, vs_pad, bounds)
-    recv_k = jnp.swapaxes(send_k, 0, 1)
-    recv_v = jnp.swapaxes(send_v, 0, 1)
-    counts = send_counts.T.sum(axis=1)
-
-    mk, mv = jax.vmap(
-        lambda rk, rv: merge_lib.merge_padded_runs_kv(rk, rv, use_pallas=config.use_pallas)
-    )(recv_k, recv_v)
-
-    return SortKVResult(mk, mv, counts, overflowed, send_counts)
-
-
-@functools.lru_cache(maxsize=32)
-def _phased_programs(config: spl.SortConfig, investigator: bool, kv: bool):
-    """Separately jitted per-phase programs for traced sorts.
-
-    The fused ``sample_sort_sim`` is one program — great for throughput,
-    opaque to attribution. When ``SortLimits(trace=True)`` asks for the
-    paper's phase breakdown, the same six steps run as four programs
-    (local sort / splitter selection / exchange / merge) so each span can
-    fence on its own output. Cached per (config, investigator, kv) like
-    the mesh programs; the untraced hot path never touches these.
-    """
-
-    def _local(x):
-        return jax.vmap(
-            lambda r: local_sort(r, tile=config.tile, use_pallas=config.use_pallas)
-        )(x)
-
-    def _local_kv(k, v):
-        return jax.vmap(
-            lambda kk, vv: local_sort_kv(kk, vv, tile=config.tile,
-                                         use_pallas=config.use_pallas)
-        )(k, v)
-
-    def _split(xs):
-        p, n = xs.shape
-        cap = config.capacity(p, n)
-        s = config.num_samples(p, n, key_bytes=xs.dtype.itemsize)
-        samples = jax.vmap(lambda r: spl.regular_sample(r, s))(xs)
+    with jax.named_scope("splitter"):
+        s = config.num_samples(p, n, key_bytes=keys.dtype.itemsize)
+        samples = jax.vmap(lambda r: spl.regular_sample(r, s))(ks)
         splitters = spl.select_splitters(samples.reshape(-1), p)
-        bounds = _bounds_all(xs, splitters, investigator)
+
+        bounds = _bounds_all(ks, splitters, investigator)
         send_counts = bounds[:, 1:] - bounds[:, :-1]
         overflowed = jnp.any(send_counts > cap)
-        return bounds, send_counts, overflowed
 
-    def _exchange(xs, bounds, send_counts):
-        p, n = xs.shape
-        cap = config.capacity(p, n)
-        fill = kops.sentinel_for(xs.dtype)
-        xs_pad = jnp.concatenate([xs, jnp.full((p, cap), fill, xs.dtype)], axis=1)
-        send = jax.vmap(lambda row, b: _gather_buckets(row, b, cap, p))(xs_pad, bounds)
-        recv = jnp.swapaxes(send, 0, 1)
-        counts = send_counts.T.sum(axis=1)
-        return recv, counts
-
-    def _exchange_kv(ks, vs, bounds, send_counts):
-        p, n = ks.shape
-        cap = config.capacity(p, n)
+    with jax.named_scope("exchange"):
         kfill = kops.sentinel_for(ks.dtype)
         vfill = kops.sentinel_for(vs.dtype)
         ks_pad = jnp.concatenate([ks, jnp.full((p, cap), kfill, ks.dtype)], axis=1)
@@ -251,79 +193,11 @@ def _phased_programs(config: spl.SortConfig, investigator: bool, kv: bool):
         recv_k = jnp.swapaxes(send_k, 0, 1)
         recv_v = jnp.swapaxes(send_v, 0, 1)
         counts = send_counts.T.sum(axis=1)
-        return recv_k, recv_v, counts
 
-    def _merge(recv):
-        return jax.vmap(
-            lambda r: merge_lib.merge_padded_runs(r, use_pallas=config.use_pallas)
-        )(recv)
+    mk, mv = jax.vmap(
+        lambda rk, rv: merge_lib.merge_padded_runs_kv(rk, rv, use_pallas=config.use_pallas)
+    )(recv_k, recv_v)
 
-    def _merge_kv(recv_k, recv_v):
-        return jax.vmap(
-            lambda rk, rv: merge_lib.merge_padded_runs_kv(
-                rk, rv, use_pallas=config.use_pallas
-            )
-        )(recv_k, recv_v)
-
-    if kv:
-        return (jax.jit(_local_kv), jax.jit(_split), jax.jit(_exchange_kv),
-                jax.jit(_merge_kv))
-    return jax.jit(_local), jax.jit(_split), jax.jit(_exchange), jax.jit(_merge)
-
-
-def sample_sort_sim_phased(
-    x: jnp.ndarray,
-    config: spl.SortConfig = spl.SortConfig(),
-    *,
-    investigator: bool = True,
-    trace,
-) -> SortResult:
-    """Traced sample sort: identical math to ``sample_sort_sim``, run as
-    four fenced phase programs recording one span each on ``trace`` —
-    local_sort, splitter, exchange, merge — with per-processor counts and
-    the per-phase imbalance the paper's tables report. Returns the same
-    ``SortResult`` so the overflow ladder applies unchanged (each ladder
-    step appends a fresh set of phase spans)."""
-    local, split, exchange, merge = _phased_programs(config, investigator, False)
-    p, n = x.shape
-    with trace.span("local_sort") as sp:
-        xs = sp.fence(local(x))
-        sp.counts([n] * p)
-    with trace.span("splitter") as sp:
-        bounds, send_counts, overflowed = sp.fence(split(xs))
-        sp.set(overflowed=bool(overflowed))
-    with trace.span("exchange") as sp:
-        recv, counts = sp.fence(exchange(xs, bounds, send_counts))
-        sp.counts(list(counts))
-    with trace.span("merge") as sp:
-        merged = sp.fence(merge(recv))
-        sp.counts(list(counts))
-    return SortResult(merged, counts, overflowed, send_counts)
-
-
-def sample_sort_sim_phased_kv(
-    keys: jnp.ndarray,
-    values: jnp.ndarray,
-    config: spl.SortConfig = spl.SortConfig(),
-    *,
-    investigator: bool = True,
-    trace,
-) -> SortKVResult:
-    """Key/value traced variant of ``sample_sort_sim_phased``."""
-    local, split, exchange, merge = _phased_programs(config, investigator, True)
-    p, n = keys.shape
-    with trace.span("local_sort") as sp:
-        ks, vs = sp.fence(local(keys, values))
-        sp.counts([n] * p)
-    with trace.span("splitter") as sp:
-        bounds, send_counts, overflowed = sp.fence(split(ks))
-        sp.set(overflowed=bool(overflowed))
-    with trace.span("exchange") as sp:
-        recv_k, recv_v, counts = sp.fence(exchange(ks, vs, bounds, send_counts))
-        sp.counts(list(counts))
-    with trace.span("merge") as sp:
-        mk, mv = sp.fence(merge(recv_k, recv_v))
-        sp.counts(list(counts))
     return SortKVResult(mk, mv, counts, overflowed, send_counts)
 
 
@@ -362,9 +236,10 @@ def sample_sort_sim_flat(
         x = keyenc.flip(x)
     res = sample_sort_sim(x, config, investigator=investigator)
     p, n = x.shape
-    flat = keyenc.compact_rows(res.values, res.counts, p * n)
-    if descending:
-        flat = keyenc.flip(flat)
-    if packspec is not None:
-        flat = keyenc.unpack_fields(flat, packspec)
+    with jax.named_scope("decode"):
+        flat = keyenc.compact_rows(res.values, res.counts, p * n)
+        if descending:
+            flat = keyenc.flip(flat)
+        if packspec is not None:
+            flat = keyenc.unpack_fields(flat, packspec)
     return FlatSortResult(flat, res.counts, res.overflowed, res.send_counts)

@@ -3,15 +3,20 @@
 Three layers, one import:
 
 * **Spans** (``obs.trace()`` / ``SortLimits(trace=True)``): wall-time
-  phase breakdown of a sort — plan, encode, stage, local sort, splitter,
-  exchange, merge, decode, D2H — with per-processor counts and measured
-  imbalance, exportable as Chrome trace-event JSON. See ``tracing``.
+  phase breakdown of a sort — plan, encode, stage, the fused sort
+  program (dispatch, overflow check), decode, D2H — with per-processor
+  counts and measured imbalance, exportable as Chrome trace-event JSON.
+  A traced sort runs the same compiled program as an untraced one. See
+  ``tracing``.
 * **Metrics** (``obs.counter/gauge/histogram``, ``obs.render_prometheus``):
   process-wide registry the serve tier, program cache, and overflow
   ladder publish into; rendered as Prometheus text exposition. See
   ``metrics``.
-* **Profiling** (``obs.annotate``): optional ``jax.profiler`` step
-  annotations on the flush/staging hot paths (``REPRO_PROFILE=1``).
+* **Profiler** (``jax.profiler``): every span site is also a
+  ``TraceAnnotation`` named ``repro.<span>``, and the in-core sort
+  programs scope each paper step with ``jax.named_scope`` (names in
+  ``tracing.PHASES``), so a captured profile splits the device time by
+  phase on the same clock as the host spans. Nothing to switch on.
 * **Flight recorder** (``obs.flight``): always-on bounded rings of
   recent request/flush summaries with per-request ``trace_id``s, dumped
   as structured incident snapshots to ``$REPRO_FLIGHT_DIR`` on anomaly
@@ -26,7 +31,7 @@ from __future__ import annotations
 
 import contextlib
 
-from repro.obs import flight, metrics, profiling, slo, tracing
+from repro.obs import flight, metrics, slo, tracing
 from repro.obs.flight import RECORDER, FlightRecorder, new_trace_id
 from repro.obs.metrics import (
     REGISTRY,
@@ -36,13 +41,11 @@ from repro.obs.metrics import (
     histogram,
     render_prometheus,
 )
-from repro.obs.profiling import annotate, set_profiling
 from repro.obs.slo import SLOConfig, SLOTracker
-from repro.obs.tracing import Span, Trace, current_trace, maybe_span, trace
+from repro.obs.tracing import PHASES, Span, Trace, current_trace, maybe_span, trace
 
 __all__ = [
     "metrics",
-    "profiling",
     "tracing",
     "flight",
     "slo",
@@ -57,8 +60,7 @@ __all__ = [
     "gauge",
     "histogram",
     "render_prometheus",
-    "annotate",
-    "set_profiling",
+    "PHASES",
     "Span",
     "Trace",
     "current_trace",

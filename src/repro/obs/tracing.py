@@ -2,10 +2,11 @@
 
 The paper's headline claims (balanced workloads, hidden communication
 latency) are *measurement* claims, so the repro needs the same
-figure-level breakdown: one ``Span`` per pipeline phase — plan, key
-encode/pack, staging, local sort, splitter selection, exchange, merge,
-decode, D2H — with per-processor element counts and the measured
-imbalance attached where a phase has a processor axis.
+figure-level breakdown: one ``Span`` per host phase — plan, key
+encode/pack, staging, the sort program (local sort, splitter selection,
+exchange and merge in one program), decode, D2H — with per-processor
+element counts and the measured imbalance attached where a phase has a
+processor axis.
 
 A ``Trace`` is created either explicitly::
 
@@ -15,8 +16,9 @@ A ``Trace`` is created either explicitly::
     tr.to_chrome_file("sort.trace.json")
 
 or implicitly via ``SortLimits(trace=True)``, in which case the planner
-builds one and attaches it as ``SortOutput.meta.trace``. Spans are flat
-(no nesting) and appended under a lock; ``coverage()`` reports the
+builds one and attaches it as ``SortOutput.meta.trace``. Spans are
+appended under a lock and may nest (``sort`` holds ``dispatch`` and
+``overflow_check``); ``coverage()`` reports the
 fraction of the trace's wall window covered by at least one span — the
 ``trace_overhead`` benchmark gate asserts >= 0.95 for a sim sort.
 
@@ -33,10 +35,20 @@ work must *fence*: ``sp.fence(arrays)`` calls ``jax.block_until_ready``
 inside the span so the measured interval includes the program it
 launched. Unfenced spans measure dispatch only — which is itself the
 paper-relevant number for overlap phases (the stream pass-1 H2D, e.g.).
+
+One clock for host and device: ``maybe_span`` also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<span>`` (recorded only
+while a profiler captures), and the in-core sort programs wrap each
+paper step in ``jax.named_scope(<phase>)`` with the names in ``PHASES``.
+A profile therefore shows the library's host phases and the device time
+of each phase of the one fused program users run; a ``Trace`` of an
+in-core sort holds one fenced ``sort`` span for that program (with
+``dispatch`` and ``overflow_check`` inside it), not a span per phase.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import threading
 import time
@@ -47,6 +59,28 @@ from repro.obs import metrics as _metrics
 _state = threading.local()
 
 _enabled = True
+
+# the paper's steps as device scopes (``jax.named_scope``) inside the
+# in-core sort programs, and as span names of the stream backend's passes
+PHASES = ("local_sort", "splitter", "exchange", "merge", "decode")
+
+
+def phase_of(op_name: str) -> str | None:
+    """The ``PHASES`` scope an op sits under, from its name-stack path
+    (an HLO op's ``op_name`` metadata, a profile's ``tf_op``), e.g.
+    ``jit(sample_sort_sim_kv)/vmap(local_sort)/sort`` -> ``local_sort``.
+    A scope entered under ``vmap`` shows as ``vmap(<scope>)``; a jitted
+    function of the same name (``jit(<name>)``) is not a scope. The
+    innermost scope wins; None when the op is under none."""
+    found = None
+    for part in op_name.split("/"):
+        part = part.partition(":")[0]
+        while part.startswith("vmap(") and part.endswith(")"):
+            part = part[5:-1]
+        if part in PHASES:
+            found = part
+    return found
+
 
 # per-phase wall time, published at trace freeze — the registry-side
 # view of the same breakdown the Trace object holds
@@ -150,10 +184,12 @@ class Trace:
         try:
             yield sp
         finally:
-            t1 = time.perf_counter()
-            with self._lock:
-                if not self._frozen:
-                    self.spans.append(Span(name, t0, t1, sp.attrs))
+            self._record(name, t0, time.perf_counter(), sp.attrs)
+
+    def _record(self, name: str, t0: float, t1: float, attrs: dict) -> None:
+        with self._lock:
+            if not self._frozen:
+                self.spans.append(Span(name, t0, t1, attrs))
 
     # ---- derived views -------------------------------------------------
 
@@ -282,18 +318,59 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-@contextlib.contextmanager
+@functools.cache
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use: the obs
+    package itself imports without jax."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
+class _Phase:
+    """The context manager ``maybe_span`` returns. A class, not a
+    generator: the span's clock reads are the last thing on entry and the
+    first on exit, so back-to-back phases leave next to no gap between
+    their spans."""
+
+    __slots__ = ("_trace", "_handle", "_annotation", "_t0")
+
+    def __init__(self, trace: "Trace | None", name: str, attrs: dict):
+        self._annotation = _annotation()(f"repro.{name}")
+        self._trace = None if trace is None or trace.frozen else trace
+        if self._trace is None:
+            self._handle = _NULL_SPAN
+        else:
+            self._handle = _OpenSpan(trace, name)
+            self._handle.attrs.update(attrs)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self._handle
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        if self._trace is not None:
+            h = self._handle
+            self._trace._record(h.name, self._t0, t1, h.attrs)
+        return False
+
+
 def maybe_span(trace: "Trace | None", name: str, **attrs):
-    """``trace.span(...)`` when a trace is active, no-op handle when not —
-    lets pipeline code instrument unconditionally with near-zero cost on
-    the untraced path. A frozen trace also degrades to the no-op handle:
-    late materialization (``.keys`` read after an ambient ``obs.trace()``
-    block exited) must not blow up, it just goes unattributed."""
-    if trace is None or not _enabled or trace.frozen:
-        yield _NULL_SPAN
-        return
-    with trace.span(name, **attrs) as sp:
-        yield sp
+    """The library's one instrumentation site. Always a
+    ``jax.profiler.TraceAnnotation`` named ``repro.<name>``, which records
+    only while a profiler captures; also a span on ``trace`` when a trace
+    is active, whose handle (``set``/``counts``/``fence``) the block gets,
+    else a no-op handle, so pipeline code instruments unconditionally
+    with near-zero cost on the untraced path. A frozen trace also
+    degrades to the no-op handle: late materialization (``.keys`` read
+    after an ambient ``obs.trace()`` block exited) must not blow up, it
+    just goes unattributed. ``obs.disabled()`` drops both."""
+    if not _enabled:
+        return contextlib.nullcontext(_NULL_SPAN)
+    return _Phase(trace, name, attrs)
 
 
 def current_trace() -> Trace | None:

@@ -34,7 +34,7 @@ from repro.core import overflow, sim
 from repro.core import planner as planner_grid
 from repro.core.splitters import SortConfig
 from repro.kernels import ops as kops
-from repro.obs.profiling import annotate as _annotate
+from repro.obs.tracing import maybe_span as _span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -199,9 +199,9 @@ def generate_runs(
             kfill = keyenc.flip_np(kfill)
         # H2D of the NEXT chunk goes on the wire while the previous
         # chunk's sort is still executing (async dispatch) — the
-        # double-buffer overlap. The profiler annotation (REPRO_PROFILE=1)
-        # makes that overlap visible in a captured device profile.
-        with _annotate("repro.stream.stage_chunk"):
+        # double-buffer overlap. The profiler annotation makes that
+        # overlap visible in a captured device profile.
+        with _span(None, "stream.stage_chunk"):
             dev_k = jax.device_put(_pad_chunk(chunk, p, per, kfill))
             dev_v = None
             if val_chunks is not None:

@@ -51,7 +51,7 @@ from repro.kernels import ops as kops
 from repro.kernels.ops import _next_pow2
 from repro.obs import flight as obs_flight
 from repro.obs import metrics as obs_metrics
-from repro.obs.profiling import annotate as _annotate
+from repro.obs.tracing import maybe_span as _span
 from repro.stream.runs import _pad_chunk
 
 # Registry mirrors of the per-instance ``stats`` dicts: process-wide
@@ -253,9 +253,9 @@ class FlushEngine:
                             flat=True, descending=descending,
                             packspec=packspec)
         t_staged = time.monotonic()
-        # profiler annotation (REPRO_PROFILE=1) brackets the flush program
-        # dispatch so captured device profiles attribute the vmapped sort
-        with _annotate("repro.service.flush_batch"):
+        # the profiler annotation brackets the flush program dispatch so
+        # captured device profiles attribute the vmapped sort
+        with _span(None, "service.flush_batch"):
             res = fn(jnp.asarray(batch))
             jax.block_until_ready(res.flat)
         t_sorted = time.monotonic()

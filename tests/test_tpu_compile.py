@@ -9,6 +9,8 @@ The topology is described inside a module fixture, never at import
 time: only one process may load the TPU library, and test collection
 must not depend on it.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -19,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from repro.core import sample_sort, sim
 from repro.core.splitters import SortConfig
 from repro.kernels import bitonic, ops
+from repro.obs.tracing import phase_of
 
 
 @pytest.fixture(scope="module")
@@ -63,6 +66,18 @@ def _lower(fn, *specs):
     return jax.jit(fn).lower(*specs).compile()
 
 
+def _unscoped_kernels_and_sorts(text: str) -> list:
+    """Pallas calls and XLA sorts of a compiled program that sit under no
+    phase scope (``obs.tracing.PHASES``) in their ``op_name`` metadata."""
+    out = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line or " sort(" in line:
+            m = re.search(r'op_name="([^"]*)"', line)
+            if m is None or phase_of(m.group(1)) is None:
+                out.append(line.strip()[:160])
+    return out
+
+
 # (rows, width) the main path hands each kernel: the 1024-wide tile sort,
 # a 128-lane row padded up from a small request's 64-element shard, and
 # the widest bitonic merge round (two 4096 runs -> one 8192 row). The
@@ -99,6 +114,7 @@ def test_sim_main_path_lowers_for_v5e(one_chip, compiled_kernels):
     spec = jax.ShapeDtypeStruct((8, 1 << 19), jnp.int32, sharding=one_chip)
     compiled = _lower(lambda x: sim.sample_sort_sim(x, SortConfig()), spec)
     assert "tpu_custom_call" in compiled.as_text()
+    assert _unscoped_kernels_and_sorts(compiled.as_text()) == []
 
 
 def test_mesh_sort_lowers_on_four_described_chips(topo, compiled_kernels):
@@ -110,4 +126,5 @@ def test_mesh_sort_lowers_on_four_described_chips(topo, compiled_kernels):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-to-all" in text
+    assert _unscoped_kernels_and_sorts(text) == []
     assert np.prod(mesh.devices.shape) == 4
