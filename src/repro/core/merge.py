@@ -6,6 +6,11 @@ merge tree pairs equal-length runs each round (the paper's "handler" that
 keeps merge inputs equally sized for cache friendliness); sentinels stay
 glued to the tail of every intermediate run, so padding never needs to be
 compacted until the very end.
+
+Key/value runs wider than one bitonic merge row are merged in merge-path
+rounds through one Pallas kernel (``kernels/merge_path.py``); keys alone,
+and everything the kernels do not take, keep the merge tree of
+``kernels/ops.py``.
 """
 from __future__ import annotations
 
@@ -43,10 +48,16 @@ def merge_padded_runs_kv(
     use_pallas: bool = True,
     stable: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Key/value variant; the value payload rides the same permutation."""
+    """Key/value variant; the value payload rides the same permutation.
+
+    Runs wider than one bitonic merge row merge in merge-path rounds
+    (``kops.merge_path_engages``), a stable merge equal to the XLA path's.
+    """
     kfill = kops.sentinel_for(keys.dtype)
     vfill = kops.sentinel_for(values.dtype)
     with jax.named_scope("merge"):
-        return kops.merge_tree_kv(_pad_runs_pow2(keys, kfill),
-                                  _pad_runs_pow2(values, vfill),
-                                  stable=stable, use_pallas=use_pallas)
+        keys, values = _pad_runs_pow2(keys, kfill), _pad_runs_pow2(values, vfill)
+        if kops.merge_path_engages(*keys.shape, keys.dtype, values.dtype,
+                                   use_pallas=use_pallas):
+            return kops.merge_path_kv(keys, values)
+        return kops.merge_tree_kv(keys, values, stable=stable, use_pallas=use_pallas)

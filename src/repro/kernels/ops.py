@@ -13,7 +13,10 @@ Responsibilities:
     records it in ``plan.reasons``,
   * expose ``tile_sort`` — a flat 1-D shard sort built exactly like the
     paper's local phase: sort fixed-size tiles ("worker threads"), then a
-    balanced pairwise merge tree (Fig. 2, ``merge_tree``).
+    balanced pairwise merge tree (Fig. 2, ``merge_tree``),
+  * merge key/value runs wider than one kernel row in merge-path rounds
+    (``merge_path_kv``, ``kernels/merge_path.py``), where ``merge_tree_kv``
+    would end in one XLA sort.
 
 The per-kernel correctness sweeps in ``tests/test_kernels.py`` validate
 every path against ``ref.py``; ``tests/test_tpu_compile.py`` lowers the
@@ -26,7 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import bitonic
+from repro.kernels import bitonic, merge_path
 
 # Above this row length the working set stops fitting a comfortable VMEM
 # tile (keys+values, in+out, double-buffered) and we fall back to lax.sort.
@@ -68,14 +71,15 @@ def _work_dtype(dtype):
 def kernel_path(*dtypes) -> str:
     """Where the row sorts and merges of arrays of ``dtypes`` (keys, then
     payloads) run when Pallas is enabled, and why when it is not the
-    kernels. Rows wider than ``MAX_PALLAS_ROW`` go to XLA whatever the
-    dtype."""
+    kernels. Merges of runs wider than one kernel row take the merge-path
+    kernel with payloads and one XLA sort without."""
     wide = [str(jnp.dtype(d)) for d in dtypes if _work_dtype(d) is None]
     if wide:
         return (f"XLA lax.sort: 64-bit elements "
                 f"({', '.join(wide)}) have no Mosaic vector lowering")
     return (f"Pallas bitonic kernels on rows of {MIN_PALLAS_ROW}-{MAX_PALLAS_ROW} "
-            f"elements, one XLA lax.sort for wider merges")
+            f"elements; wider merges: key/value runs in Pallas merge-path "
+            f"rounds, keys alone in one XLA lax.sort")
 
 
 def sentinel_for(dtype: jnp.dtype) -> jnp.ndarray:
@@ -188,6 +192,45 @@ def merge_rows_kv(ak, av, bk, bv, *, stable: bool = True, use_pallas: bool = Tru
         interpret=_interpret(),
     )
     return ok[:, : 2 * n].astype(ak.dtype), ov[:, : 2 * n].astype(av.dtype)
+
+
+def merge_path_engages(runs: int, width: int, *dtypes, use_pallas: bool) -> bool:
+    """Whether a key/value merge of ``runs`` sorted runs of ``width``
+    elements of ``dtypes`` runs as merge-path rounds: Pallas on, 32-bit
+    lanes, runs too wide for one bitonic merge row, and a layout the
+    kernel takes (``merge_path.geometry``)."""
+    return (use_pallas and all(_work_dtype(d) is not None for d in dtypes)
+            and not _merge_in_kernel(width, *dtypes, use_pallas=use_pallas)
+            and merge_path.geometry(runs, width) is not None)
+
+
+@jax.custom_batching.custom_vmap
+def _merge_path_kv(keys, values):
+    *lead, runs, width = keys.shape
+    kdtype, vdtype = _work_dtype(keys.dtype), _work_dtype(values.dtype)
+    k, v = merge_path.merge_runs_kv(keys.reshape(-1, runs, width).astype(kdtype),
+                                    values.reshape(-1, runs, width).astype(vdtype),
+                                    interpret=_interpret())
+    n = runs * width
+    return (k.reshape(*lead, n).astype(keys.dtype),
+            v.reshape(*lead, n).astype(values.dtype))
+
+
+@_merge_path_kv.def_vmap
+def _merge_path_kv_vmap(axis_size, in_batched, keys, values):
+    # a batch axis is one more leading axis of independent merges (a
+    # Pallas call with manual DMAs has no batching rule of its own)
+    keys, values = (x if b else jnp.broadcast_to(x, (axis_size, *x.shape))
+                    for x, b in zip((keys, values), in_batched))
+    return _merge_path_kv(keys, values), (True, True)
+
+
+@jax.jit
+def merge_path_kv(keys, values):
+    """Merge (..., R, C) row-sorted key/value runs into (..., R*C): the
+    merge-path rounds of ``kernels/merge_path.py`` in the kernels' 32-bit
+    lanes, bit for bit the stable XLA sort of the concatenated runs."""
+    return _merge_path_kv(keys, values)
 
 
 def _xla_sort_kv(keys, values):

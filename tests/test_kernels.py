@@ -6,7 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.core import merge as merge_lib
+from repro.kernels import merge_path, ops, ref
 
 RNG = np.random.default_rng(42)
 
@@ -113,6 +114,8 @@ def test_kernel_path_names_64bit_fallback():
 
     reasons = repro.plan(np.arange(8, dtype=np.int32)).reasons
     assert any("Pallas bitonic kernels" in r for r in reasons)
+    kv = repro.plan(np.arange(8, dtype=np.int32), np.arange(8, dtype=np.int32)).reasons
+    assert any("merge-path" in r for r in kv)
 
 
 def test_lax_fallback_path_equivalence():
@@ -125,3 +128,37 @@ def test_lax_fallback_path_equivalence():
 def test_sentinels():
     assert np.isposinf(float(ops.sentinel_for(jnp.float32)))
     assert int(ops.sentinel_for(jnp.int32)) == np.iinfo(np.int32).max
+
+
+def _skewed_runs(procs, runs, width, dtype):
+    """Sorted runs of the paper's right_skewed input (floor(u^6 * 64): 64
+    distinct keys, about half of them 0) with all-sentinel tails on the
+    first and last run, and random int32 payloads (not in run order, so
+    only a stable merge reproduces them)."""
+    keys = np.floor(RNG.random((procs, runs, width)) ** 6 * 64)
+    keys = np.sort(keys - 8 if dtype == np.float32 else keys, axis=-1).astype(dtype)
+    fill = np.asarray(ops.sentinel_for(jnp.dtype(dtype)))
+    keys[:, 0, width // 2:] = fill
+    keys[:, -1, width // 3:] = fill
+    vals = RNG.integers(-2**31, 2**31 - 1, (procs, runs, width)).astype(np.int32)
+    return jnp.asarray(keys), jnp.asarray(vals)
+
+
+# runs wider than one bitonic merge row, at widths that are no multiple of
+# the kernel's tile; 5 runs are padded to 8 with all-sentinel runs
+@pytest.mark.parametrize("runs,width", [(2, 5000), (5, 4500), (8, 4097)])
+@pytest.mark.parametrize("dtype", [np.int32, np.uint32, np.float32])
+def test_merge_path_kv_bit_identical_to_xla_sort(runs, width, dtype):
+    keys, vals = _skewed_runs(2, runs, width, dtype)
+    assert width % merge_path.TILE
+    assert ops.merge_path_engages(8 if runs == 5 else runs, width, keys.dtype,
+                                  vals.dtype, use_pallas=True)
+    # per processor under vmap, as the sim merges: the batch folds into
+    # the kernel's processor axis
+    got_k, got_v = jax.vmap(merge_lib.merge_padded_runs_kv)(keys, vals)
+    pad_k = jax.vmap(merge_lib._pad_runs_pow2, (0, None))(keys, ops.sentinel_for(keys.dtype))
+    pad_v = jax.vmap(merge_lib._pad_runs_pow2, (0, None))(vals, ops.sentinel_for(vals.dtype))
+    want_k, want_v = jax.vmap(ops._xla_sort_kv)(pad_k.reshape(2, -1), pad_v.reshape(2, -1))
+    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+

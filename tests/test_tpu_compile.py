@@ -7,7 +7,8 @@ too much VMEM). Interpret-mode tests cannot see those failures.
 
 The topology is described inside a module fixture, never at import
 time: only one process may load the TPU library, and test collection
-must not depend on it.
+must not depend on it. One test counts the programs a stream kv bucket
+merge compiles on the CPU, with the persistent cache off as here.
 """
 import re
 
@@ -21,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from repro.core import sample_sort, sim
 from repro.core.splitters import SortConfig
 from repro.kernels import bitonic, ops
+from repro.stream.external_merge import merge_segments_kv
 from repro.obs.tracing import phase_of
 
 
@@ -128,3 +130,74 @@ def test_mesh_sort_lowers_on_four_described_chips(topo, compiled_kernels):
     assert "all-to-all" in text
     assert _unscoped_kernels_and_sorts(text) == []
     assert np.prod(mesh.devices.shape) == 4
+
+
+def _kernel_bodies_and_sorts(text: str, phase: str):
+    """(distinct Mosaic kernel bodies, XLA sorts) under ``phase``."""
+    bodies, sorts = set(), 0
+    for line in text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m is None or phase_of(m.group(1)) != phase:
+            continue
+        if 'custom_call_target="tpu_custom_call"' in line:
+            bodies.add(line.partition("backend_config=")[2])
+        elif " sort(" in line:
+            sorts += 1
+    return bodies, sorts
+
+
+def test_sim_kv_merge_is_one_merge_path_kernel_for_v5e(one_chip, compiled_kernels):
+    """The benchmark cell's program: 2^26 kv over 8 virtual processors,
+    each merging 8 received runs of 1,310,752. The merge's rounds share
+    one kernel body and no XLA sort is left in the merge; the local sort
+    keeps its last round in XLA."""
+    spec = jax.ShapeDtypeStruct((8, 1 << 23), jnp.int32, sharding=one_chip)
+    assert SortConfig().capacity(8, 1 << 23) == 1_310_752
+    compiled = _lower(lambda k, v: sim.sample_sort_sim_kv(k, v, SortConfig()), spec, spec)
+    text = compiled.as_text()
+    bodies, sorts = _kernel_bodies_and_sorts(text, "merge")
+    assert len(bodies) == 1 and "merge_path_kv" in text
+    assert sorts == 0
+    assert _kernel_bodies_and_sorts(text, "local_sort")[1] == 1
+    assert _unscoped_kernels_and_sorts(text) == []
+
+
+def test_stream_kv_bucket_merge_adds_at_most_one_program():
+    """A bucket wider than one bitonic merge row compiles at most one
+    program more than the XLA merge of a bucket of another shape (the
+    stream pads bucket widths to a power of two), and nothing the second
+    time."""
+    import jax.monitoring
+
+    compiles = []
+
+    def on_duration(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    rng = np.random.default_rng(3)
+
+    def bucket(width):
+        lens = (width, width - 700, width - 60)
+        keys = [np.sort(rng.integers(0, 50, n).astype(np.int32)) for n in lens]
+        return keys, [rng.integers(0, 1 << 30, n).astype(np.int32) for n in lens]
+
+    def programs(segs, use_pallas):
+        del compiles[:]
+        out = merge_segments_kv(*segs, use_pallas=use_pallas)
+        return len(compiles), out
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        xla_programs, _ = programs(bucket(5000), use_pallas=False)  # width 8192
+        segs = bucket(9000)  # width 16384
+        assert ops.merge_path_engages(4, 16384, jnp.int32, jnp.int32, use_pallas=True)
+        new_programs, (mk, mv) = programs(segs, use_pallas=True)
+        again, _ = programs(segs, use_pallas=True)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert new_programs <= xla_programs + 1
+    assert again == 0
+    want_k, want_v = merge_segments_kv(*segs, use_pallas=False)
+    np.testing.assert_array_equal(mk, want_k)
+    np.testing.assert_array_equal(mv, want_v)
